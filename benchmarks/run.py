@@ -272,6 +272,8 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_smoke.json",
                     help="JSON path for --smoke results")
     ns = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if ns.smoke:
         results = smoke(ns.out)
         violations = results["lowering_check"]["violations"]

@@ -26,7 +26,7 @@ from .pipeline import (design_pipeline, split_reductions, plan_queues,
                        PipelinedGraph, Pipeline, Stage, QueueSpec)
 from .balance import solve_allocation, balance, BalanceResult
 from .costmodel import (
-    A100, V5E, HwSpec, v5e_mesh, evaluate, cost_bsp, cost_vertical,
+    A100, V5E, HwSpec, v5e_mesh, device_hw, evaluate, cost_bsp, cost_vertical,
     cost_kitsune, cost_kernel_site, calibrate, roofline, RooflineTerms,
     utilization_quadrants,
     PEAK_FLOPS_PER_CHIP, HBM_BW_PER_CHIP, ICI_BW_PER_LINK,
@@ -58,7 +58,7 @@ __all__ = [
     "materialize_queues", "OpQueue", "DedupeInfo", "dedupe_programs",
     "PipelinedGraph", "Pipeline", "Stage", "QueueSpec",
     "solve_allocation", "balance", "BalanceResult",
-    "A100", "V5E", "HwSpec", "v5e_mesh", "evaluate", "cost_bsp",
+    "A100", "V5E", "HwSpec", "v5e_mesh", "device_hw", "evaluate", "cost_bsp",
     "cost_vertical", "cost_kitsune", "cost_kernel_site", "calibrate",
     "roofline", "RooflineTerms", "utilization_quadrants",
     "queue_bandwidth", "VMEM_QUEUE", "ICI_QUEUE", "L2_QUEUE_A100",

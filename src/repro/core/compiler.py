@@ -39,7 +39,7 @@ from typing import Any, Callable
 import jax
 
 from .balance import BalanceResult, balance as _balance_pipeline
-from .costmodel import GraphCost, HwSpec, evaluate, v5e_mesh
+from .costmodel import GraphCost, HwSpec, device_hw, evaluate
 from .executor import (Engine, ExecutionReport, _shape_key, executable_cache,
                        init_params, make_backend)
 from .graph import Graph, graph_fingerprint
@@ -71,6 +71,8 @@ class CompilerOptions:
     min_sf_size          smallest op count an sf-node may have
     balance              run the ILP load-balancing pass (Algorithm 2)
     hw                   HwSpec the balance pass and estimate() default to
+                         (None: `costmodel.device_hw()`, the attached TPU
+                         host, or the modelled v5e-8 off-TPU)
     disable              pass names to skip (each falls back to its identity
                          form; e.g. disabling `epilogue_fuse` yields one
                          stage per op)
@@ -126,7 +128,7 @@ class CompilerOptions:
         return frozenset(dis)
 
     def resolved_hw(self) -> HwSpec:
-        return self.hw if self.hw is not None else v5e_mesh(8)
+        return self.hw if self.hw is not None else device_hw()
 
     def cache_key(self) -> tuple:
         """Hashable identity for the executable cache (hooks excluded: they

@@ -69,6 +69,30 @@ def v5e_mesh(chips: int) -> HwSpec:
                   ici_bw=4 * 50e9)
 
 
+# The attached TPU host, keyed by (jax `device_kind`, local device count).
+# Per-chip peaks from Google Cloud's "TPU v5e" page: 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s; v5e hosts hold 1, 4 or 8 chips.
+TPU_HOSTS = {("TPU v5 lite", 1): V5E,
+             ("TPU v5 lite", 4): v5e_mesh(4),
+             ("TPU v5 lite", 8): v5e_mesh(8)}
+
+# The modelled target of the CPU tests and the paper-table benchmarks.
+MODELLED_HW = v5e_mesh(8)
+
+
+def device_hw() -> HwSpec:
+    """HwSpec of the attached devices: the TPU_HOSTS entry on a TPU (a TPU
+    host not in the table is an error, never a guess), MODELLED_HW off-TPU."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return MODELLED_HW
+    key = (jax.devices()[0].device_kind, jax.local_device_count())
+    if key not in TPU_HOSTS:
+        raise ValueError(f"no HwSpec for TPU host {key}; add its published "
+                         f"peaks to costmodel.TPU_HOSTS")
+    return TPU_HOSTS[key]
+
+
 # ---------------------------------------------------------------------------
 # Per-op BSP times
 # ---------------------------------------------------------------------------

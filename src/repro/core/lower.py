@@ -46,8 +46,9 @@ back to the jnp closure for execution.  Verdicts are cached process-wide by
 repeat compiles pay nothing.  `policy="always"` (the default for direct
 `lower_pipelines` calls) preserves the historical force-lower behavior.
 
-Off-TPU the kernels run in Pallas interpret mode (`interpret=True`), keeping
-the differential tests executable on CPU CI.  On real TPUs the lowering also
+`kernel_config()` is the one platform probe: off-TPU the kernels run in
+Pallas interpret mode (`interpret=True`), keeping the differential tests
+executable on CPU CI.  On real TPUs they compile, and the lowering also
 autotunes each kernel's block sizes over a small per-kernel candidate grid
 (`tile_candidates` in the kernel modules; cached in kernels.autotune).
 """
@@ -87,19 +88,17 @@ MEASURE_MARGIN = 1.3
 MEASURE_REPS = 5
 
 
-def _interpret() -> bool:
-    """Pallas interpret mode everywhere but real TPUs (CPU CI, tests)."""
-    return jax.default_backend() != "tpu"
-
-
-def _kernel_cfg():
-    """ONE platform probe per lowering; the resulting KernelConfig is
-    threaded through every matcher and kernel-call factory (the call
-    closures must not re-probe the backend on every invocation)."""
+def kernel_config():
+    """THE platform probe: the Pallas kernels compiled (and autotuned) on a
+    TPU, in interpret mode everywhere else (CPU tests).  Every entry point
+    that runs kernels -- the lowering pass, the launchers, chip_smoke.py --
+    takes its KernelConfig from here; the result is threaded through every
+    matcher and kernel-call factory (the call closures must not re-probe
+    the backend on every invocation)."""
     from repro.kernels import KernelConfig
-    interp = _interpret()
-    return KernelConfig(use_pallas=True, interpret=interp,
-                        autotune=not interp)
+    on_tpu = jax.default_backend() == "tpu"
+    return KernelConfig(use_pallas=True, interpret=not on_tpu,
+                        autotune=on_tpu)
 
 
 # ---------------------------------------------------------------------------
@@ -113,13 +112,16 @@ class Verdict:
     `source` records which tier decided: "forced" (policy bypass),
     "cost" (roofline estimates were conclusive), "measured" (the one-shot
     microbenchmark settled it).  Times are microseconds; measured fields
-    stay None when the estimate tier was conclusive."""
+    stay None when the estimate tier was conclusive.  `error` holds why a
+    microbenchmark that should have decided could not run (the estimate
+    decided instead)."""
     decision: str                        # "lowered" | "declined"
     source: str                          # "forced" | "cost" | "measured"
     est_kernel_us: float = 0.0
     est_closure_us: float = 0.0
     meas_kernel_us: float | None = None
     meas_closure_us: float | None = None
+    error: str | None = None
 
     @property
     def lowered(self) -> bool:
@@ -129,8 +131,11 @@ class Verdict:
         if self.source == "forced":
             return "forced by policy"
         if self.source == "cost":
-            return (f"cost est kernel {self.est_kernel_us:.1f}us vs "
-                    f"closure {self.est_closure_us:.1f}us")
+            why = (f"cost est kernel {self.est_kernel_us:.1f}us vs "
+                   f"closure {self.est_closure_us:.1f}us")
+            if self.error:
+                why += f" (measurement failed: {self.error})"
+            return why
         return (f"measured kernel {self.meas_kernel_us:.1f}us vs "
                 f"closure {self.meas_closure_us:.1f}us")
 
@@ -416,7 +421,7 @@ def _try_hinted_atomic(g: Graph, n: Node, mset: set[str], taken: set[str],
     def make(c):
         call = factory(list(n.inputs), act, c)
         if tuple_valued:
-            # atomic pjit nodes are tuple-valued (projections index them):
+            # atomic jit nodes are tuple-valued (projections index them):
             # the kernel call must honor the same convention as the eval
             # closure
             return lambda vals, params: (call(vals, params),)
@@ -774,7 +779,9 @@ def _tune_match(g: Graph, km: KernelMatch, cfg):
         return make_kernel_fn(km._factory(replace(cfg, **cand)))
 
     choice = autotune(key, cands, build, args)
-    blocks = {k: v for k, v in choice.items() if k != "us"}
+    if "refused" in choice:
+        km.meta["refused"] = choice["refused"]
+    blocks = {k: v for k, v in choice.items() if k not in ("us", "refused")}
     if not blocks:
         return cfg
     km.meta.update(blocks)
@@ -820,11 +827,13 @@ def _decide(g: Graph, km: KernelMatch, hw: HwSpec, cfg,
         return Verdict(dec, "cost", est_k, est_c)
     try:
         t_k, t_c = _measure_site(g, km, cfg)
-    except Exception:
+    except Exception as exc:  # noqa: BLE001 - recorded in the verdict
         # measurement infeasible (e.g. unevaluable traced operand): the
-        # estimate is all we have
+        # estimate decides, and the verdict says why it had to
         dec = "lowered" if est_k <= est_c else "declined"
-        return Verdict(dec, "cost", est_k, est_c)
+        why = (str(exc).strip().splitlines() or [""])[0][:160]
+        return Verdict(dec, "cost", est_k, est_c,
+                       error=f"{type(exc).__name__}: {why}")
     mk, mc = t_k * 1e6, t_c * 1e6
     dec = "lowered" if mk * MEASURE_MARGIN <= mc else "declined"
     return Verdict(dec, "measured", est_k, est_c, mk, mc)
@@ -859,7 +868,7 @@ def lower_pipeline(g: Graph, sf_name: str, members: list[str], *,
     """Greedy scan of the member list (topo order) against the kernel
     matchers; unmatched non-free ops get a fallback reason."""
     if cfg is None:
-        cfg = _kernel_cfg()
+        cfg = kernel_config()
     mset = set(members)
     taken: set[str] = set()
     matches: list[KernelMatch] = []
@@ -914,7 +923,7 @@ def lower_pipelines(g: Graph, members_of: dict[str, list[str]], *,
     if policy not in ("always", "cost", "auto"):
         raise ValueError(f"unknown lowering policy {policy!r}")
     if cfg is None:
-        cfg = _kernel_cfg()
+        cfg = kernel_config()
     plan = LoweringPlan({name: lower_pipeline(g, name, members, cfg=cfg)
                          for name, members in members_of.items()})
     if policy != "always":

@@ -28,18 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # newer jax exports shard_map at top level; older builds don't
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-# newer jax renamed check_rep -> check_vma; pass whichever this build has
-_SM_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else {"check_rep": False})
+from jax import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -146,5 +135,5 @@ def make_spatial_pipeline(mesh, stage_fn, n_stages: int, axis_name: str = "stage
         fn, mesh=mesh,
         in_specs=(P(axis_name), P()),   # params stage-sharded, xs replicated
         out_specs=P(),
-        **_SM_NOCHECK,
+        check_vma=False,
     )

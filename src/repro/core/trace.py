@@ -31,11 +31,11 @@ Import rules:
     layer loop of every zoo model becomes a real dataflow graph); scans
     bigger than `max_unroll_eqns` stay opaque single nodes
   * multi-output primitives      -> one tuple-valued node + free projections
-  * pjit of a registered atomic (see `atomic()`) -> ONE node of the
+  * jit of a registered atomic (see `atomic()`) -> ONE node of the
     registered kind (e.g. fused attention), flops from the registry;
     `atomic_vjp()` registers a custom-vjp PAIR so `jax.grad` traces keep
     both the forward and the backward as single (kernel-lowerable) nodes
-  * other pjit / custom_jvp / custom_vjp / remat -> inlined
+  * other jit / custom_jvp / custom_vjp / remat -> inlined
 """
 from __future__ import annotations
 
@@ -55,7 +55,11 @@ from .graph import Graph, Node, TensorSpec
 # beyond it the scan becomes one opaque node (still numerically exact).
 MAX_UNROLL_EQNS = 8192
 # Consts up to this size are deduplicated by value (zeros/iota tiles repeat
-# across unrolled iterations); larger ones only by object identity.
+# across unrolled iterations); larger ones only by object identity.  It also
+# bounds constant folding: a literal-only eqn with a larger result (zeros of
+# a weight's shape, a (B, S, vocab) iota) stays in the graph, so its program
+# makes it on the device instead of the app holding a materialized copy in
+# device memory for its whole life.
 _CONST_DEDUP_BYTES = 1 << 16
 
 # Primitive -> op-kind classification ---------------------------------------
@@ -84,7 +88,7 @@ _TRANSCENDENTAL = {"exp", "exp2", "expm1", "log", "log1p", "log2", "tanh",
                    "lgamma"}
 
 _INLINE_JAXPR_PARAMS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
-_INLINE_PRIMS = {"pjit", "closed_call", "core_call", "xla_call",
+_INLINE_PRIMS = {"jit", "closed_call", "core_call", "xla_call",
                  "custom_jvp_call", "custom_vjp_call",
                  "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
                  "remat", "remat2", "checkpoint", "custom_transpose_call",
@@ -118,7 +122,7 @@ def atomic(fn: Callable, kind: str, *,
     """Wrap `fn` so the tracer imports any call to it as ONE node of `kind`.
 
     The wrapper jits `fn` under a marker name; when the tracer meets the
-    resulting pjit eqn it emits a single graph node (resource class and
+    resulting jit eqn it emits a single graph node (resource class and
     pattern code of `kind`) whose eval closure runs the whole sub-jaxpr --
     this is how fused attention stays one "attention" op instead of
     dissolving into its einsum/softmax soup.
@@ -224,6 +228,11 @@ def _is_dropvar(v) -> bool:
     return type(v).__name__ == "DropVar"
 
 
+def _out_bytes(eqn) -> int:
+    return sum(math.prod(v.aval.shape) * np.dtype(v.aval.dtype).itemsize
+               for v in eqn.outvars)
+
+
 def _stable_params(params: dict) -> str:
     """Deterministic, address-free repr of eqn params (fingerprint input)."""
     parts = []
@@ -244,7 +253,7 @@ def _stable_params(params: dict) -> str:
 
 
 def _sub_jaxprs(params: dict) -> list["jex_core.Jaxpr"]:
-    """Every jaxpr-valued param (pjit `jaxpr`, while `body_jaxpr` /
+    """Every jaxpr-valued param (jit `jaxpr`, while `body_jaxpr` /
     `cond_jaxpr`, cond `branches` tuple, ...), as open jaxprs."""
     found = []
     for v in params.values():
@@ -427,8 +436,10 @@ class _Importer:
     def eqn(self, eqn, env: dict) -> None:
         prim = eqn.primitive
         name = prim.name
-        # 1. constant folding: no traced operands -> evaluate at trace time
-        if all(isinstance(v, jex_core.Literal) for v in eqn.invars):
+        # 1. constant folding: no traced operands and a small result ->
+        #    evaluate at trace time
+        if (all(isinstance(v, jex_core.Literal) for v in eqn.invars)
+                and _out_bytes(eqn) <= _CONST_DEDUP_BYTES):
             vals = prim.bind(*[v.val for v in eqn.invars], **eqn.params)
             vals = list(vals) if prim.multiple_results else [vals]
             for var, val in zip(eqn.outvars, vals):

@@ -16,12 +16,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # newer jax exports shard_map at top level; older builds don't
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
-from repro.core.queue import _SM_NOCHECK, spatial_pipeline
+from repro.core.queue import spatial_pipeline
 
 
 def stack_stage_params(layer_params, n_stages: int):
@@ -61,5 +58,5 @@ def run_pipelined(mesh, layer_fn, layer_params, xs, n_stages: int,
     stage_params = stack_stage_params(layer_params, n_stages)
     pipe = make_pipelined_stack(mesh, layer_fn, n_layers, n_stages, axis_name)
     fn = shard_map(pipe, mesh=mesh, in_specs=(P(axis_name), P()),
-                   out_specs=P(), **_SM_NOCHECK)
+                   out_specs=P(), check_vma=False)
     return fn(stage_params, xs)

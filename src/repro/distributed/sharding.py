@@ -190,6 +190,23 @@ class Sharder:
         return jax.tree_util.tree_unflatten(
             treedef, [specs[path_str(kp)] for kp, v in flat])
 
+    def state_shardings(self, state) -> dict:
+        """Shardings for a whole train state {"params", "opt"} (train/step.py
+        `make_train_state`): an optimizer leaf kept at a parameter's
+        position with that parameter's shape (Adam moments, Adafactor's
+        unfactored second moment) takes the parameter's sharding; the step
+        counter and factored or quantized moments are replicated."""
+        params, opt = state["params"], state["opt"]
+        p_sh = self.params_shardings(params)
+        rep = self.replicated()
+
+        def moments(p, sh, sub):
+            return jax.tree.map(
+                lambda x: sh if x.shape == p.shape else rep, sub)
+
+        inner = jax.tree.map(moments, params, p_sh, opt.inner)
+        return {"params": p_sh, "opt": opt._replace(step=rep, inner=inner)}
+
     def data_sharding(self, ndim: int = 2) -> NamedSharding:
         """(B, S, ...) batch over (pod, data)."""
         return NamedSharding(self.mesh, P(self.batch_axes, *([None] * (ndim - 1))))

@@ -86,7 +86,10 @@ def autotune(key: tuple, candidates: Iterable[dict],
     """Pick the fastest candidate for one kernel site.
 
     `build(candidate)` returns the callable to time (it is jit-compiled
-    here); `candidates` are dicts of KernelConfig block overrides.  The
+    here); `candidates` are dicts of KernelConfig block overrides.  A
+    candidate the compiler refuses (a tile the backend cannot lay out, more
+    VMEM than it may use) is skipped and named in the winner's `refused`
+    entry; if every candidate is refused, the last refusal is raised.  The
     winner (augmented with its measured `us`) is cached under `key`."""
     cands = list(candidates)
     if not cands:
@@ -99,11 +102,24 @@ def autotune(key: tuple, candidates: Iterable[dict],
         _TUNE.put(key, choice)
         return choice
     best, best_t = None, float("inf")
+    refused: list[str] = []
     for cand in cands:
-        t = time_fn(jax.jit(build(cand)), args, iters)
+        try:
+            compiled = jax.jit(build(cand)).lower(*args).compile()
+        except Exception as exc:  # noqa: BLE001 - any compiler refusal
+            if len(refused) == len(cands) - 1:
+                raise RuntimeError(f"autotune: the compiler refused all "
+                                   f"{len(cands)} candidates") from exc
+            why = (str(exc).strip().splitlines() or [type(exc).__name__])[0]
+            label = ",".join(f"{k}={v}" for k, v in sorted(cand.items()))
+            refused.append(f"{label}: {why[:80]}")
+            continue
+        t = time_fn(compiled, args, iters)
         if t < best_t:
             best, best_t = cand, t
     choice = dict(best)
     choice["us"] = best_t * 1e6
+    if refused:
+        choice["refused"] = tuple(refused)
     _TUNE.put(key, choice)
     return choice

@@ -182,21 +182,22 @@ def _decode_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, scale, n_s,
     l_ref[0, 0] = l_c
 
 
-def _decode_kernel_dyn(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, *,
-                       scale):
+def _decode_kernel_dyn(valid_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                       *, scale, hkv):
     """Decode chunk kernel with a RUNTIME per-sequence valid length.
 
-    `valid_ref` holds this (batch, kv-head) program's valid length -- the
+    `valid_ref` is the scalar-prefetched (B,) valid-length vector -- the
     serving engine's per-slot position clock (each slot attends to exactly
     its own [0, valid) cache range; a refilled slot never sees the previous
-    occupant's stale entries)."""
+    occupant's stale entries).  Program bh serves batch element bh // hkv."""
+    bh = pl.program_id(0)
     schunk = pl.program_id(1)
     q = q_ref[0]
     k = k_ref[0]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     base = schunk * k.shape[0]
     ki = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(ki < valid_ref[0, 0], s, NEG_INF)
+    s = jnp.where(ki < valid_ref[bh // hkv], s, NEG_INF)
     m_c = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m_c)
     l_c = jnp.sum(p, axis=-1, keepdims=True)
@@ -237,46 +238,40 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qr = q.reshape(b * hkv, group, d)   # group heads share this kv head
     kr = k.reshape(b * hkv, s_len, d)
     vr = v.reshape(b * hkv, s_len, d)
-    static = isinstance(valid_len, int)
-    if static:
+    # `*_` absorbs the scalar-prefetch operand of the runtime-length form
+    in_specs = [
+        pl.BlockSpec((1, group, d), lambda bh, j, *_: (bh, 0, 0)),
+        pl.BlockSpec((1, block_s, d), lambda bh, j, *_: (bh, j, 0)),
+        pl.BlockSpec((1, block_s, d), lambda bh, j, *_: (bh, j, 0)),
+    ]
+    out_specs = [
+        pl.BlockSpec((1, 1, group, d), lambda bh, j, *_: (bh, j, 0, 0)),
+        pl.BlockSpec((1, 1, group, 1), lambda bh, j, *_: (bh, j, 0, 0)),
+        pl.BlockSpec((1, 1, group, 1), lambda bh, j, *_: (bh, j, 0, 0)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((b * hkv, n_s, group, d), jnp.float32),
+        jax.ShapeDtypeStruct((b * hkv, n_s, group, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b * hkv, n_s, group, 1), jnp.float32),
+    ]
+    if isinstance(valid_len, int):
         kern = functools.partial(_decode_kernel, scale=scale, n_s=n_s,
                                  valid_len=valid_len)
-        in_specs = [
-            pl.BlockSpec((1, group, d), lambda bh, j: (bh, 0, 0)),
-            pl.BlockSpec((1, block_s, d), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_s, d), lambda bh, j: (bh, j, 0)),
-        ]
-        args = (qr, kr, vr)
+        o, m, l = pl.pallas_call(
+            kern, grid=(b * hkv, n_s), in_specs=in_specs,
+            out_specs=out_specs, out_shape=out_shape,
+            interpret=interpret)(qr, kr, vr)
     else:
         vl = jnp.asarray(valid_len, jnp.int32)
         if vl.ndim == 0:
             vl = jnp.broadcast_to(vl, (b,))
-        # (B,) -> (B*Hkv, 1): program bh serves batch element bh // hkv
-        vl = jnp.repeat(vl, hkv).reshape(b * hkv, 1)
-        kern = functools.partial(_decode_kernel_dyn, scale=scale)
-        in_specs = [
-            pl.BlockSpec((1, group, d), lambda bh, j: (bh, 0, 0)),
-            pl.BlockSpec((1, block_s, d), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_s, d), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, 1), lambda bh, j: (bh, 0)),
-        ]
-        args = (qr, kr, vr, vl)
-    o, m, l = pl.pallas_call(
-        kern,
-        grid=(b * hkv, n_s),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, group, d), lambda bh, j: (bh, j, 0, 0)),
-            pl.BlockSpec((1, 1, group, 1), lambda bh, j: (bh, j, 0, 0)),
-            pl.BlockSpec((1, 1, group, 1), lambda bh, j: (bh, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, n_s, group, d), jnp.float32),
-            jax.ShapeDtypeStruct((b * hkv, n_s, group, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b * hkv, n_s, group, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+        kern = functools.partial(_decode_kernel_dyn, scale=scale, hkv=hkv)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b * hkv, n_s), in_specs=in_specs,
+            out_specs=out_specs)
+        o, m, l = pl.pallas_call(
+            kern, grid_spec=grid_spec, out_shape=out_shape,
+            interpret=interpret)(vl, qr, kr, vr)
     out = combine_partials(o, m, l)     # (b*hkv, group, d)
     return out.reshape(b, hq, 1, d).astype(q.dtype)
 

@@ -40,19 +40,50 @@ _ACTS = {
 }
 
 
+# Scoped-VMEM budget Mosaic assumes when a kernel sets no limit (v5e).
+DEFAULT_SCOPED_VMEM = 16 << 20
+
+
+def block_h_for(hdim: int, cap: int) -> int:
+    """Hidden-dim tile: the largest multiple of 128 (the TPU lane width)
+    that divides `hdim` and is <= `cap`; the whole dim when no multiple of
+    128 divides it (small CPU-test widths)."""
+    for bh in range(cap - cap % 128, 0, -128):
+        if hdim % bh == 0:
+            return bh
+    return hdim
+
+
 def tile_candidates(m: int, hdim: int) -> list[dict]:
     """Autotune grid for the fused-MLP kernels (fwd and bwd share tiles --
-    ops._blocks is the single tiling rule): (block_m, block_h) pairs that
-    exactly divide (m, hdim), deduped, historical default included.  The
-    autotuner (kernels/autotune.py) times each at first-build."""
-    bms = [bm for bm in (32, 64, 128, 256) if m % bm == 0] or [1]
-    bhs = [bh for bh in (128, 256, 512, 1024) if hdim % bh == 0] or [hdim]
-    cands = [{"block_m": bm, "block_h": bh} for bm in bms for bh in bhs]
-    default = {"block_m": min(128, m) if m % min(128, m) == 0 else 1,
-               "block_h": 512 if hdim % 512 == 0 else hdim}
-    if default not in cands:
-        cands.append(default)
-    return cands
+    ops._blocks is the single tiling rule): the distinct (block_m, block_h)
+    tiles that rule yields for a sweep of caps, so every candidate is one
+    the TPU compiler accepts.  The historical 128/512 default is among the
+    caps.  The autotuner (kernels/autotune.py) times each at first-build."""
+    bms = sorted({min(bm, m) for bm in (32, 64, 128, 256)})
+    bhs = sorted({block_h_for(hdim, cap) for cap in (128, 256, 512, 1024)})
+    return [{"block_m": bm, "block_h": bh} for bm in bms for bh in bhs]
+
+
+def _compiler_params(block_bytes: int, scratch_bytes: int):
+    """Raise the kernel's scoped-VMEM limit when its working set (blocks
+    double-buffered by the pipeline, plus scratch, plus headroom for the
+    f32 temporaries of the tile math) exceeds the default."""
+    need = (2 * block_bytes + scratch_bytes) * 3 // 2
+    if need <= DEFAULT_SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(need, 100 << 20))
+
+
+def _nbytes(*blocks) -> int:
+    """Bytes of (shape, dtype) blocks."""
+    total = 0
+    for shape, dtype in blocks:
+        n = jnp.dtype(dtype).itemsize
+        for d in shape:
+            n *= d
+        total += n
+    return total
 
 
 # ---------------------------------------------------------------------------
@@ -106,6 +137,12 @@ def fused_mlp_fwd(x: jax.Array, w1: jax.Array, w2: jax.Array,
     d_out = w2.shape[1]
     assert m % block_m == 0 and hdim % block_h == 0, (m, hdim, block_m, block_h)
     n_m, n_h = m // block_m, hdim // block_h
+    dt = x.dtype
+    params = _compiler_params(
+        _nbytes(((block_m, d_in), dt), ((d_in, block_h), w1.dtype),
+                ((block_h, d_out), w2.dtype), ((block_m, d_out), dt)),
+        _nbytes(((block_m, d_out), jnp.float32),
+                ((block_m, block_h), jnp.float32)))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, act=act, n_h=n_h),
         grid=(n_m, n_h),
@@ -117,6 +154,7 @@ def fused_mlp_fwd(x: jax.Array, w1: jax.Array, w2: jax.Array,
         out_specs=pl.BlockSpec((block_m, d_out), lambda i, h: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d_out), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
     )(x, w1, w2)
 
@@ -131,8 +169,15 @@ def fused_mlp_swiglu_fwd(x: jax.Array, wg: jax.Array, wu: jax.Array,
     m, d_in = x.shape
     _, hdim = wg.shape
     d_out = wd.shape[1]
-    assert m % block_m == 0 and hdim % block_h == 0
+    assert m % block_m == 0 and hdim % block_h == 0, (m, hdim, block_m, block_h)
     n_m, n_h = m // block_m, hdim // block_h
+    dt = x.dtype
+    params = _compiler_params(
+        _nbytes(((block_m, d_in), dt), ((d_in, block_h), wg.dtype),
+                ((d_in, block_h), wu.dtype), ((block_h, d_out), wd.dtype),
+                ((block_m, d_out), dt)),
+        _nbytes(((block_m, d_out), jnp.float32),
+                ((3 * block_m, block_h), jnp.float32)))
     return pl.pallas_call(
         functools.partial(_fwd_kernel_swiglu, act=act, n_h=n_h),
         grid=(n_m, n_h),
@@ -145,6 +190,7 @@ def fused_mlp_swiglu_fwd(x: jax.Array, wg: jax.Array, wu: jax.Array,
         out_specs=pl.BlockSpec((block_m, d_out), lambda i, h: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d_out), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
     )(x, wg, wu, wd)
 
@@ -270,7 +316,19 @@ def fused_mlp_swiglu_bwd(x, wg, wu, wd, dy, *, act: str = "silu",
     m, d_in = x.shape
     _, hdim = wg.shape
     d_out = wd.shape[1]
+    assert m % block_m == 0 and hdim % block_h == 0, (m, hdim, block_m, block_h)
     n_m, n_h = m // block_m, hdim // block_h
+    dt, f32 = x.dtype, jnp.float32
+    in_bytes = _nbytes(((block_m, d_in), dt), ((d_in, block_h), wg.dtype),
+                       ((d_in, block_h), wu.dtype),
+                       ((block_h, d_out), wd.dtype), ((block_m, d_out), dt))
+    tile_tmp = _nbytes(((6 * block_m, block_h), f32))
+    dx_params = _compiler_params(
+        in_bytes + _nbytes(((block_m, d_in), dt)),
+        _nbytes(((block_m, d_in), f32)) + tile_tmp)
+    dw_params = _compiler_params(
+        in_bytes + _nbytes(((2 * d_in + d_out, block_h), f32)),
+        _nbytes(((2 * d_in + d_out, block_h), f32)) + tile_tmp)
     dx = pl.pallas_call(
         functools.partial(_bwd_dx_kernel_swiglu, act=act, n_h=n_h),
         grid=(n_m, n_h),
@@ -284,6 +342,7 @@ def fused_mlp_swiglu_bwd(x, wg, wu, wd, dy, *, act: str = "silu",
         out_specs=pl.BlockSpec((block_m, d_in), lambda i, h: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d_in), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d_in), jnp.float32)],
+        compiler_params=dx_params,
         interpret=interpret,
     )(x, wg, wu, wd, dy)
     dwg, dwu, dwd = pl.pallas_call(
@@ -309,6 +368,7 @@ def fused_mlp_swiglu_bwd(x, wg, wu, wd, dy, *, act: str = "silu",
         scratch_shapes=[pltpu.VMEM((d_in, block_h), jnp.float32),
                         pltpu.VMEM((d_in, block_h), jnp.float32),
                         pltpu.VMEM((block_h, d_out), jnp.float32)],
+        compiler_params=dw_params,
         interpret=interpret,
     )(x, wg, wu, wd, dy)
     return (dx, dwg.astype(wg.dtype), dwu.astype(wu.dtype),
@@ -320,7 +380,18 @@ def fused_mlp_bwd(x, w1, w2, dy, *, act: str = "gelu", block_m: int = 128,
     m, d_in = x.shape
     _, hdim = w1.shape
     d_out = w2.shape[1]
+    assert m % block_m == 0 and hdim % block_h == 0, (m, hdim, block_m, block_h)
     n_m, n_h = m // block_m, hdim // block_h
+    dt, f32 = x.dtype, jnp.float32
+    in_bytes = _nbytes(((block_m, d_in), dt), ((d_in, block_h), w1.dtype),
+                       ((block_h, d_out), w2.dtype), ((block_m, d_out), dt))
+    tile_tmp = _nbytes(((4 * block_m, block_h), f32))
+    dx_params = _compiler_params(
+        in_bytes + _nbytes(((block_m, d_in), dt)),
+        _nbytes(((block_m, d_in), f32)) + tile_tmp)
+    dw_params = _compiler_params(
+        in_bytes + _nbytes(((d_in + d_out, block_h), f32)),
+        _nbytes(((d_in + d_out, block_h), f32)) + tile_tmp)
     dx = pl.pallas_call(
         functools.partial(_bwd_dx_kernel, act=act, n_h=n_h),
         grid=(n_m, n_h),
@@ -333,6 +404,7 @@ def fused_mlp_bwd(x, w1, w2, dy, *, act: str = "gelu", block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, d_in), lambda i, h: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d_in), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d_in), jnp.float32)],
+        compiler_params=dx_params,
         interpret=interpret,
     )(x, w1, w2, dy)
     dw1, dw2 = pl.pallas_call(
@@ -354,6 +426,7 @@ def fused_mlp_bwd(x, w1, w2, dy, *, act: str = "gelu", block_m: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((d_in, block_h), jnp.float32),
                         pltpu.VMEM((block_h, d_out), jnp.float32)],
+        compiler_params=dw_params,
         interpret=interpret,
     )(x, w1, w2, dy)
     return dx, dw1.astype(w1.dtype), dw2.astype(w2.dtype)

@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-`use_pallas` selects the dataflow kernels (TPU; `interpret=True` on CPU for
-tests); otherwise the ref.py XLA path runs -- models call these so the whole
-framework switches implementation with one config flag.
+`use_pallas` selects the dataflow kernels (compiled on TPU; `interpret=True`
+on CPU for tests); otherwise the ref.py XLA path runs -- models call these so
+the whole framework switches implementation with one config flag.  A Pallas
+config must state `interpret`: `repro.core.lower.kernel_config()` derives it
+from the attached platform.
 
 `fused_mlp` carries a custom_vjp whose backward is itself a dataflow kernel
 pair (Fig 2(c) multicast -- see fused_mlp.py).
@@ -18,15 +20,15 @@ import jax.numpy as jnp
 from . import ref
 from .flash_attention import combine_partials, flash_attention, flash_decode
 from .paged_attention import paged_flash_decode
-from .fused_mlp import (fused_mlp_bwd, fused_mlp_fwd, fused_mlp_swiglu_bwd,
-                        fused_mlp_swiglu_fwd)
+from .fused_mlp import (block_h_for, fused_mlp_bwd, fused_mlp_fwd,
+                        fused_mlp_swiglu_bwd, fused_mlp_swiglu_fwd)
 from .queue_reduce import queue_reduce
 
 
 @dataclass(frozen=True)
 class KernelConfig:
     use_pallas: bool = False
-    interpret: bool = True      # CPU validation mode; False on real TPUs
+    interpret: bool | None = None   # Pallas interpret mode (CPU tests only)
     block_m: int = 128
     block_h: int = 512
     block_q: int = 128
@@ -34,6 +36,15 @@ class KernelConfig:
     block_s: int = 256          # flash_decode split-K chunk
     block_r: int = 128          # queue_reduce row tile
     autotune: bool = False      # search tile_candidates grids at lower time
+
+    def __post_init__(self):
+        if self.use_pallas and self.interpret is None:
+            raise ValueError(
+                "KernelConfig(use_pallas=True) must state interpret=; "
+                "repro.core.lower.kernel_config() picks it for the platform")
+        if self.interpret and jax.default_backend() == "tpu":
+            raise ValueError("Pallas interpret mode is for CPU tests; on a "
+                             "TPU the kernels run compiled")
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int):
@@ -46,13 +57,12 @@ def _pad_to(x: jax.Array, axis: int, mult: int):
 
 
 def _blocks(m: int, hdim: int, cfg: KernelConfig) -> tuple[int, int]:
-    """Kernel tilings that exactly divide small/CPU shapes: block_m falls
-    back to 1, block_h to the full hidden dim.  The ONE tiling rule for
-    every fused-MLP wrapper, forward and backward -- the two directions must
-    always pick the same tiles for the same shapes."""
-    bm = min(cfg.block_m, m) if m % min(cfg.block_m, m) == 0 else 1
-    bh = cfg.block_h if hdim % cfg.block_h == 0 else hdim
-    return bm, bh
+    """The ONE tiling rule for every fused-MLP wrapper, forward and
+    backward -- the two directions must always pick the same tiles for the
+    same shapes.  Only tiles the TPU compiler accepts: block_m is the whole
+    row dim when it fits under the cap, else the cap (a multiple of 8; the
+    wrappers pad rows up to it); block_h via `fused_mlp.block_h_for`."""
+    return min(cfg.block_m, m), block_h_for(hdim, cfg.block_h)
 
 
 # ---------------------------------------------------------------------------

@@ -220,8 +220,6 @@ def _build_cell(cfg, shape_name: str, mesh, *, opt_kind: str):
 
 def _cost_triple(compiled) -> tuple[float, float, float]:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax wraps the dict in a list
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)), coll["total"])

@@ -8,14 +8,12 @@ from __future__ import annotations
 
 import jax
 
-try:  # AxisType landed in newer jax; older builds default to Auto anyway
-    from jax.sharding import AxisType
+from jax.sharding import AxisType
 
-    def _axis_types_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # pragma: no cover - version compat
-    def _axis_types_kw(n: int) -> dict:
-        return {}
+
+def _axis_types_kw(n: int) -> dict:
+    """Auto (compiler-propagated) sharding on every mesh axis."""
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

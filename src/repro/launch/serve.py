@@ -26,6 +26,8 @@ import time
 import jax
 
 from repro.configs import get_config
+from repro.core.lower import kernel_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import get_model
 from repro.serve import (AsyncServingEngine, EngineError, PagedServingEngine,
                          ServeConfig, ServingEngine, parse_fault_plan)
@@ -56,6 +58,7 @@ def main():
     ap.add_argument("--nan-guard", action="store_true",
                     help="fail slots whose decode logits go NaN/Inf")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -72,22 +75,24 @@ def main():
                      nan_guard=args.nan_guard, max_queue=args.max_queue,
                      default_deadline_s=args.deadline_s)
 
+    kernels = kernel_config()
     t0 = time.time()
     if args.engine == "legacy":
-        eng = ServingEngine(cfg, params, sc, eos_id=-1)
+        eng = ServingEngine(cfg, params, sc, kernels=kernels, eos_id=-1)
         for rid, p in enumerate(prompts):
             eng.submit(rid, p)
         done = eng.run_until_done()
         extra = ""
     elif args.engine == "paged":
-        eng = PagedServingEngine(cfg, params, sc, eos_id=-1)
+        eng = PagedServingEngine(cfg, params, sc, kernels=kernels, eos_id=-1)
         for rid, p in enumerate(prompts):
             eng.submit(p, rid=rid)
         done = eng.run_until_done()
         extra = f" stats={eng.stats()}"
         failed = eng.failed
     else:
-        with AsyncServingEngine(cfg, params, sc, eos_id=-1) as eng:
+        with AsyncServingEngine(cfg, params, sc, kernels=kernels,
+                                eos_id=-1) as eng:
             handles = [eng.submit(p) for p in prompts]
             done, failed = {}, {}
             for h in handles:

@@ -27,14 +27,43 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import Checkpointer
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import _axis_types_kw
 from repro.configs import get_config
+from repro.configs.base import ArchConfig
 from repro.data import DataConfig, SyntheticLM
 from repro.distributed.sharding import NULL, Sharder
-from repro.optim import adafactor, adamw, cosine_schedule
+from repro.optim import Optimizer, adafactor, adamw, cosine_schedule
 from repro.runtime import StragglerMonitor, Supervisor
 from repro.train import (TrainConfig, compile_train_step, make_train_state,
                          make_train_step)
+
+
+def make_optimizer(cfg: ArchConfig, steps: int) -> Optimizer:
+    """AdamW on a cosine schedule; Adafactor above 100B parameters, where
+    fp32 Adam moments outgrow the chips' HBM."""
+    if cfg.param_count() > 100e9:
+        return adafactor(1e-2)
+    return adamw(cosine_schedule(3e-4, warmup=20, total=steps))
+
+
+def init_train_state(cfg: ArchConfig, opt: Optimizer, sharder=NULL,
+                     seed: int = 0) -> dict:
+    """Seeded train state; under a mesh every leaf -- parameters AND
+    optimizer moments -- is placed by the Sharder's rules."""
+    state = make_train_state(cfg, opt, jax.random.PRNGKey(seed))
+    if sharder is not NULL:
+        state = jax.device_put(state, sharder.state_shardings(state))
+    return state
+
+
+def jit_train_step(cfg: ArchConfig, opt: Optimizer, tc: TrainConfig,
+                   sharder=NULL):
+    """The plain-jit training step.  The old state is donated, so
+    parameters and moments update in place (feed each call the previous
+    call's output state)."""
+    return jax.jit(make_train_step(cfg, opt, tc, sharder=sharder),
+                   donate_argnums=(0,))
 
 
 def main():
@@ -56,6 +85,7 @@ def main():
                          "fwd+bwd+optimizer step, state donated in place) "
                          "instead of a plain jit; single-device only")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -68,9 +98,7 @@ def main():
     else:
         sharder = NULL
 
-    giant = cfg.param_count() > 100e9
-    opt = adafactor(1e-2) if giant else adamw(
-        cosine_schedule(3e-4, warmup=20, total=args.steps))
+    opt = make_optimizer(cfg, args.steps)
     tc = TrainConfig(remat=True, microbatches=args.microbatches,
                      xent_chunk=min(512, args.seq))
     if args.compile_mode is not None and n_dev > 1:
@@ -92,7 +120,7 @@ def main():
                       else "(no kernel lowering in this mode)", flush=True)
             return compiled["app"](state, batch)
     else:
-        step_fn = jax.jit(make_train_step(cfg, opt, tc, sharder=sharder))
+        step_fn = jit_train_step(cfg, opt, tc, sharder)
     data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                                   global_batch=args.batch))
     ck = Checkpointer(args.ckpt, keep=3, async_save=True)
@@ -101,11 +129,7 @@ def main():
     mon = StragglerMonitor()
 
     def init_state():
-        state = make_train_state(cfg, opt, jax.random.PRNGKey(0))
-        if sharder is not NULL:
-            sh = sharder.params_shardings(state["params"])
-            state["params"] = jax.tree.map(jax.device_put, state["params"], sh)
-        return state
+        return init_train_state(cfg, opt, sharder)
 
     def one_step(state, step):
         t0 = time.time()

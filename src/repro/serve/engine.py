@@ -441,6 +441,10 @@ class PagedKVExecutor:
     the final count, `initialize_cache(n)` materializes the page pools."""
 
     DEFAULT_BUDGET = 256 * 1024 * 1024   # no device stats (CPU): 256 MiB
+    # share of the device's bytes_limit the engine plans for (vLLM's
+    # gpu_memory_utilization default): the rest is left to the allocator
+    # and the loaded executables, which the tick's memory_analysis omits
+    DEVICE_UTILIZATION = 0.9
 
     def __init__(self, cfg: ArchConfig, params, sc: ServeConfig, *,
                  kernels: KernelConfig = KernelConfig(), sharder=NULL,
@@ -466,38 +470,38 @@ class PagedKVExecutor:
     def _device_budget(self) -> int:
         if self.sc.mem_budget_bytes is not None:
             return self.sc.mem_budget_bytes
-        try:
-            stats = jax.local_devices()[0].memory_stats() or {}
-            limit = stats.get("bytes_limit")
-            if limit:
-                return int(limit)
-        except Exception:
-            pass
-        return self.DEFAULT_BUDGET
+        stats = jax.local_devices()[0].memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        if not limit:
+            return self.DEFAULT_BUDGET
+        return int(limit * self.DEVICE_UTILIZATION)
 
     def profile_run(self) -> int:
-        """Working-set bytes of one compiled decode tick (C=1, 1-block view,
-        probe-sized pool) -- the activation term of the capacity model.
-        Raises MemoryError at the `executor.profile` fault site; real
-        lowering failures degrade to 0 (capacity model loses only the
-        activation term)."""
+        """Working-set bytes of the widest compiled tick the engine runs (a
+        full prefill chunk over the max_blocks view, probe-sized pool): its
+        temporaries plus the outputs that do not alias the donated state --
+        the activation term of the capacity model.  Raises MemoryError at
+        the `executor.profile` fault site; a real compile failure propagates
+        (a tick the backend refuses must stop engine construction, not
+        shrink the capacity model)."""
         if self.fault is not None and self.fault.check("executor.profile"):
             raise MemoryError("injected OOM at executor.profile")
         sc = self.sc
+        n_steps = max(1, sc.prefill_chunk)
         probe = functools.partial(paged_tick, cfg=self.cfg,
                                   kernels=self.kernels, sharder=self.sharder,
-                                  block_size=sc.block_size, n_steps=1,
+                                  block_size=sc.block_size, n_steps=n_steps,
                                   mode=sc.paged_attention)
-        state = self._abstract_state(n_steps=1, v_blocks=1, num_blocks=1)
+        state = self._abstract_state(n_steps=n_steps,
+                                     v_blocks=self.max_blocks, num_blocks=1)
         p_abs = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype),
             self.params)
-        try:
-            compiled = jax.jit(probe).lower(p_abs, state).compile()
-            mem = compiled.memory_analysis()
-            return int(getattr(mem, "temp_size_in_bytes", 0) or 0)
-        except Exception:
-            return 0
+        compiled = jax.jit(probe, donate_argnums=(1,)).lower(
+            p_abs, state).compile()
+        mem = compiled.memory_analysis()
+        return int(mem.temp_size_in_bytes + mem.output_size_in_bytes
+                   - mem.alias_size_in_bytes)
 
     def _abstract_state(self, *, n_steps: int, v_blocks: int,
                         num_blocks: int) -> dict:
@@ -623,6 +627,7 @@ class PagedServingEngine:
         self._steps: dict[tuple[int, int], Any] = {}
         self._view_buckets = self._make_view_buckets()
         self.ticks = 0
+        self.last_logits = None
         self.tokens_out = 0
         self.peak_active = 0
         # analytic per-tick KV bytes for BOTH tick data paths, accumulated
@@ -1123,6 +1128,7 @@ class PagedServingEngine:
             self.kp, self.vp = out["kp"], out["vp"]
         for name in self.aux:
             self.aux[name] = out[name]
+        self.last_logits = out["logits"]     # (B, V) of this tick, on device
         nxt = np.asarray(out["tokens_next"]).copy()
         self.pos = np.asarray(out["pos"], np.int64).copy()
         self._progressed = True

@@ -1,30 +1,19 @@
 """Test-suite bootstrap.
 
-The property tests use `hypothesis` when it is installed (the `test` extra
-in pyproject.toml).  On a clean checkout without it, a minimal deterministic
-stand-in is registered instead so `python -m pytest` still collects and runs
-everything: each `@given` test executes a small fixed set of examples drawn
-deterministically from its strategies (no shrinking, no randomization).
-
-Per-test timeouts follow the same pattern: `pytest-timeout` (test extra)
-enforces the `timeout` ini / `@pytest.mark.timeout(N)` markers in CI so a
-deadlocked serving engine fails the job in minutes instead of hanging it;
-on a checkout without the plugin, a SIGALRM-based fallback below enforces
-the same markers (main-thread, POSIX only -- elsewhere it degrades to a
+Per-test timeouts: `pytest-timeout` (test extra) enforces the `timeout`
+ini / `@pytest.mark.timeout(N)` markers in CI so a deadlocked serving
+engine fails the job in minutes instead of hanging it; on a checkout
+without the plugin, a SIGALRM-based fallback below enforces the same
+markers (main-thread, POSIX only -- elsewhere it degrades to a
 no-op rather than breaking collection).
 """
 from __future__ import annotations
 
-import functools
 import importlib.util
 import signal
-import sys
 import threading
-import types
 
 import pytest
-
-_N_EXAMPLES = 3
 
 
 # ---------------------------------------------------------------------------
@@ -76,91 +65,3 @@ if importlib.util.find_spec("pytest_timeout") is None:
         finally:
             signal.setitimer(signal.ITIMER_REAL, 0)
             signal.signal(signal.SIGALRM, old)
-
-
-def _install_hypothesis_stub() -> None:
-    class _Strategy:
-        """Deterministic value source: example(i) -> i-th representative."""
-
-        def __init__(self, gen):
-            self.example = gen
-
-    def integers(min_value=0, max_value=1 << 30):
-        span = [min_value, max_value, (min_value + max_value) // 2]
-        return _Strategy(lambda i: span[i % len(span)])
-
-    def sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda i: elements[i % len(elements)])
-
-    def lists(elem, min_size=0, max_size=None, **_):
-        hi = max_size if max_size is not None else min_size + 2
-
-        def gen(i):
-            size = min_size + (i % (hi - min_size + 1))
-            return [elem.example(i + j + 1) for j in range(size)]
-
-        return _Strategy(gen)
-
-    def floats(min_value=0.0, max_value=1.0, **_):
-        span = [min_value, max_value, (min_value + max_value) / 2]
-        return _Strategy(lambda i: span[i % len(span)])
-
-    def booleans():
-        return _Strategy(lambda i: bool(i % 2))
-
-    def just(value):
-        return _Strategy(lambda i: value)
-
-    def tuples(*strategies):
-        return _Strategy(lambda i: tuple(s.example(i) for s in strategies))
-
-    def given(*gargs, **gkwargs):
-        if gargs:
-            raise NotImplementedError(
-                "hypothesis stub supports keyword strategies only")
-
-        def deco(fn):
-            # No functools.wraps: it would expose the wrapped signature and
-            # pytest would then demand fixtures for the strategy arguments.
-            def wrapper(*args, **kwargs):
-                for i in range(_N_EXAMPLES):
-                    drawn = {k: s.example(i) for k, s in gkwargs.items()}
-                    fn(*args, **kwargs, **drawn)
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__qualname__ = getattr(fn, "__qualname__", fn.__name__)
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            wrapper.hypothesis_stub = True
-            return wrapper
-
-        return deco
-
-    def settings(**_):
-        return lambda fn: fn
-
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers = integers
-    st.sampled_from = sampled_from
-    st.lists = lists
-    st.floats = floats
-    st.booleans = booleans
-    st.just = just
-    st.tuples = tuples
-
-    hyp = types.ModuleType("hypothesis")
-    hyp.given = given
-    hyp.settings = settings
-    hyp.strategies = st
-    hyp.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
-    hyp.__stub__ = True
-
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st
-
-
-try:
-    import hypothesis  # noqa: F401  (real library present: use it)
-except ModuleNotFoundError:
-    _install_hypothesis_stub()

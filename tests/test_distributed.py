@@ -58,10 +58,7 @@ class TestSpatialPipeline:
             from jax.sharding import PartitionSpec as P
             from repro.launch.mesh import _axis_types_kw
             from repro.core.queue import ring_push
-            try:
-                from jax import shard_map
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             mesh = jax.make_mesh((8,), ("stage",), **_axis_types_kw(1))
             def f(x):
                 return ring_push(x, "stage", 8)
@@ -81,27 +78,27 @@ class TestShardedTrainStep:
             from repro.launch.mesh import _axis_types_kw
             from repro.configs import get_config
             from repro.distributed.sharding import Sharder
+            from repro.launch.train import init_train_state, jit_train_step
             from repro.optim import adamw
-            from repro.train import TrainConfig, make_train_state, make_train_step
+            from repro.train import TrainConfig
             mesh = jax.make_mesh((2, 4), ("data", "model"),
                                  **_axis_types_kw(2))
             sharder = Sharder(mesh)
             cfg = get_config("gemma3-1b").reduced()
             opt = adamw(1e-3)
-            state = make_train_state(cfg, opt)
-            shardings = sharder.params_shardings(state["params"])
-            state["params"] = jax.tree.map(
-                lambda p, s: jax.device_put(p, s), state["params"], shardings)
-            step = jax.jit(make_train_step(cfg, opt, TrainConfig(remat=True),
-                                           sharder=sharder))
+            state = init_train_state(cfg, opt, sharder)
+            step = jit_train_step(cfg, opt, TrainConfig(remat=True), sharder)
             batch = {"tokens": jax.device_put(
                 jnp.zeros((4, 32), jnp.int32), sharder.data_sharding(2))}
             state, m = step(state, batch)
             state, m = step(state, batch)
             assert jnp.isfinite(m["loss"]), m
-            # params must actually be distributed
+            # params AND their optimizer moments must actually be distributed
             w = state["params"]["blocks"]["sub0"]["mlp"]["wg"]
             assert len(w.sharding.device_set) > 1
+            for mom in jax.tree.leaves(
+                    state["opt"].inner["blocks"]["sub0"]["mlp"]["wg"]):
+                assert mom.sharding.is_equivalent_to(w.sharding, w.ndim), mom
             print("SHARDED_STEP_OK", float(m["loss"]))
         """)
         assert "SHARDED_STEP_OK" in out
@@ -137,10 +134,7 @@ class TestCompression:
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
             from repro.launch.mesh import _axis_types_kw
-            try:
-                from jax import shard_map
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from repro.optim.compression import error_feedback_allreduce, init_residuals
             mesh = jax.make_mesh((8,), ("data",), **_axis_types_kw(1))
             g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
