@@ -32,12 +32,12 @@ Pieces:
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field, replace
 from typing import Any, Callable
 
 import jax
 
+from ..spans import span
 from .balance import BalanceResult, balance as _balance_pipeline
 from .costmodel import GraphCost, HwSpec, device_hw, evaluate
 from .executor import (Engine, ExecutionReport, _shape_key, executable_cache,
@@ -156,6 +156,8 @@ class CompileState:
 
 @dataclass
 class PassRecord:
+    """One pass of a compile; `seconds` is its `pass/<name>` span's
+    (repro.spans)."""
     name: str
     seconds: float
     disabled: bool = False
@@ -385,10 +387,10 @@ class PassManager:
         for name in self.pass_names:
             run_fn, skip_fn = _PASSES[name]
             fn = skip_fn if name in disabled else run_fn
-            t0 = time.perf_counter()
-            summary = fn(state, options)
-            dt = time.perf_counter() - t0
-            records.append(PassRecord(name, dt, name in disabled, summary))
+            with span(f"pass/{name}") as sp:
+                summary = fn(state, options)
+            records.append(PassRecord(name, sp.seconds, name in disabled,
+                                      summary))
             if options.dump_ir is not None:
                 options.dump_ir(name, state)
         return records
@@ -563,12 +565,19 @@ class TracedApp(CompiledApp):
                  state: CompileState, pass_records: list[PassRecord],
                  donate_feeds: frozenset[str] = frozenset()):
         self.traced = traced
+        self._calls = 0
         super().__init__(traced.graph, options, state, pass_records,
                          donate_feeds)
 
     def __call__(self, *args):
-        report = self.run(self.traced.feeds(*args))
-        return self.traced.unflatten_outputs(report.outputs)
+        with span("run", call=self._calls):
+            self._calls += 1
+            with span("feeds"):
+                feeds = self.traced.feeds(*args)
+                key, buf = self._engine.feed(feeds, {})
+            report = self._engine.launch(key, buf, feeds, {})
+            with span("outputs"):
+                return self.traced.unflatten_outputs(report.outputs)
 
     def run(self, feeds: dict[str, jax.Array], params: dict | None = None,
             ) -> ExecutionReport:
@@ -630,10 +639,10 @@ def compile(graph: Graph | Callable, *args,
             raise TypeError("repro.compile(fn, ...) needs example_inputs")
         if not isinstance(example_inputs, (tuple, list)):
             example_inputs = (example_inputs,)
-        t0 = time.perf_counter()
-        traced = trace_fn(graph, *tuple(example_inputs),
-                          roll_scans=options.roll_scans)
-        rec = PassRecord("trace", time.perf_counter() - t0, False,
+        with span("pass/trace") as sp:
+            traced = trace_fn(graph, *tuple(example_inputs),
+                              roll_scans=options.roll_scans)
+        rec = PassRecord("trace", sp.seconds, False,
                          f"{len(traced.graph.nodes)} nodes, "
                          f"{len(traced.consts)} consts")
         donate = set(donate_feeds)

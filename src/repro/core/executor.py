@@ -30,8 +30,9 @@ Execution itself is driven by per-shape ExecutionPlans: the first run per
 feed/param shape signature resolves every value name to an integer slot,
 binds the cached executables directly, and decides which dead intermediates
 to donate; steady-state `Engine.run` is then a tight loop over prebound
-executables (benchmarks/bench_dispatch.py measures the dispatch overhead
-against the legacy dict-driven loop, kept as `Engine.run_legacy`).
+executables, each launch under a `program` span (repro.spans).  The legacy
+dict-driven loop stays as `Engine.run_legacy`, the plan's differential
+oracle.
 """
 from __future__ import annotations
 
@@ -47,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..spans import span
 from .graph import Graph, Node, graph_fingerprint, subgraph_interface
 from .patterns import Selection, select_subgraphs
 
@@ -291,9 +293,10 @@ class VerdictCache:
         with self._lock:
             self._store[key] = verdict
 
-    def keys(self):
+    def items(self) -> list[tuple[Any, Any]]:
+        """(key, verdict) of every decided site."""
         with self._lock:
-            return list(self._store)
+            return list(self._store.items())
 
     def stats(self) -> dict[str, int]:
         with self._lock:
@@ -619,11 +622,12 @@ class _BoundStep:
     over these with no dict keying, no cache lookups, no shape hashing.
     Programs with no params are compiled WITHOUT the psub argument (an empty
     dict still costs a pytree flatten on every dispatch)."""
-    __slots__ = ("call", "in_slots", "out_slots", "pkeys", "release",
-                 "donation")
+    __slots__ = ("call", "program", "in_slots", "out_slots", "pkeys",
+                 "release", "donation")
 
     def __init__(self, exe, spec: _StepSpec, pkeys: tuple[str, ...]):
         self.call = exe.compiled
+        self.program = spec.prog.name
         self.in_slots = spec.in_slots
         self.out_slots = spec.out_slots
         self.pkeys = pkeys
@@ -674,6 +678,27 @@ def _compile_step(st) -> Callable:
     return step
 
 
+def _spanned(fn: Callable, name: str, **args) -> Callable:
+    """`fn` run under one span, bound once per plan step so the hot loop
+    looks nothing up for it."""
+    def step(buf, params):
+        with span(name, **args):
+            fn(buf, params)
+    return step
+
+
+def _plan_step(st) -> Callable:
+    """A plan step's closure under its span: `program` for an executable
+    launch, `inline` for a free op that dispatches device work.  Output
+    entries are identities and run bare."""
+    fn = _compile_step(st)
+    if type(st) is not _FreeSpec:
+        return _spanned(fn, "program", program=st.program)
+    if st.node.kind == "output":
+        return fn
+    return _spanned(fn, "inline", op=st.node.kind)
+
+
 class ExecutionPlan:
     """Everything `run()` needs for one (feed, param) shape signature:
     prebound executables, slot wiring, and precomputed traffic totals.
@@ -684,7 +709,7 @@ class ExecutionPlan:
 
     def __init__(self, steps, bytes_accessed, temp_bytes, n_programs):
         self.steps = steps
-        self.fns = tuple(_compile_step(st) for st in steps)
+        self.fns = tuple(_plan_step(st) for st in steps)
         self.bytes_accessed = bytes_accessed
         self.temp_bytes = temp_bytes
         self.n_programs = n_programs
@@ -732,9 +757,9 @@ class Engine:
     integer slots, cache keys and shape keys built once, executables bound
     directly, intermediates in a flat buffer list, and arguments donated
     where the value has no later consumer.  Steady-state `run()` is then a
-    loop over prebound executables with near-zero Python overhead (see
-    benchmarks/bench_dispatch.py; `run_legacy` keeps the historical
-    dict-driven loop as the measured baseline and differential oracle)."""
+    loop over prebound executables with near-zero Python overhead
+    (`run_legacy` keeps the historical dict-driven loop as the differential
+    oracle)."""
 
     # plans an engine keeps live; beyond this the least-recent shape's plan
     # (and its pinned executable refs) is dropped and rebuilt on next use
@@ -834,16 +859,28 @@ class Engine:
         via the process-wide cache); later calls replay the prebound
         executables.  measure=False only zeroes the traffic/program
         accounting, matching the historical GraphExecutor contract."""
-        key = (_plan_key(feeds), _plan_key(params))
-        plan = self._plans.get(key)
-        if plan is None:
-            return self._build_and_run(key, feeds, params, measure)
-        self._plans.move_to_end(key)
+        key, buf = self.feed(feeds, params)
+        return self.launch(key, buf, feeds, params, measure)
+
+    def feed(self, feeds: dict[str, jax.Array], params: dict,
+             ) -> tuple[tuple, list]:
+        """One call's plan key and its value buffer, feeds in their
+        slots (the first half of `run`)."""
         buf: list[Any] = [None] * self._n_slots
         for s, name in self._feed_slots:
             if name not in feeds:
                 raise KeyError(f"missing feed for {name}")
             buf[s] = feeds[name]
+        return (_plan_key(feeds), _plan_key(params)), buf
+
+    def launch(self, key: tuple, buf: list, feeds: dict[str, jax.Array],
+               params: dict, measure: bool = True) -> ExecutionReport:
+        """Run the plan for `key` over a buffer from `feed` (the second
+        half of `run`); the first call per key builds the plan."""
+        plan = self._plans.get(key)
+        if plan is None:
+            return self._build_and_run(key, buf, feeds, params, measure)
+        self._plans.move_to_end(key)
         for step in plan.fns:
             step(buf, params)
         outs = {name: buf[s] for name, s in self._run_out_slots}
@@ -852,14 +889,9 @@ class Engine:
         return ExecutionReport(outs, plan.bytes_accessed, plan.n_programs,
                                plan.temp_bytes, plan.n_programs, 0)
 
-    def _build_and_run(self, key: tuple, feeds: dict, params: dict,
-                       measure: bool) -> ExecutionReport:
+    def _build_and_run(self, key: tuple, buf: list, feeds: dict,
+                       params: dict, measure: bool) -> ExecutionReport:
         """First call per shape signature: execute while binding the plan."""
-        buf: list[Any] = [None] * self._n_slots
-        for s, name in self._feed_slots:
-            if name not in feeds:
-                raise KeyError(f"missing feed for {name}")
-            buf[s] = feeds[name]
         bound: list[Any] = []
         total_bytes = total_temp = 0.0
         n_programs = hits = misses = 0
@@ -874,8 +906,12 @@ class Engine:
                 (donated_ids if i in seen_ids else seen_ids).add(i)
         for spec in self._steps:
             if type(spec) is _FreeSpec:
-                buf[spec.out_slot] = _eval_node(
-                    spec.node, [buf[i] for i in spec.in_slots], None)
+                ins = [buf[i] for i in spec.in_slots]
+                if spec.node.kind == "output":
+                    buf[spec.out_slot] = _eval_node(spec.node, ins, None)
+                else:
+                    with span("inline", op=spec.node.kind):
+                        buf[spec.out_slot] = _eval_node(spec.node, ins, None)
                 bound.append(spec)
             else:
                 prog = spec.prog
@@ -926,8 +962,9 @@ class Engine:
                     misses += 1
                 else:
                     hits += 1
-                outs = (exe.compiled(psub, *ins) if pkeys
-                        else exe.compiled(*ins))
+                with span("program", program=prog.name):
+                    outs = (exe.compiled(psub, *ins) if pkeys
+                            else exe.compiled(*ins))
                 st = _BoundStep(exe, spec, pkeys)
                 for o, v in zip(st.out_slots, outs):
                     buf[o] = v
@@ -952,6 +989,13 @@ class Engine:
 
     def _build_positional(self, prog: Program, ins: tuple, psub: dict,
                           donate: tuple[int, ...]) -> _Executable:
+        """Lower and compile one program, or load it from JAX's cache.
+
+        The program is jitted as `kitsune.<program name>`, which names its
+        XLA module in a profiler trace.  A struct-keyed executable is built
+        once per class, so it carries the name of the class's first
+        program; names follow from the graph alone and are the same in
+        every process."""
         if psub:
             def wrapped(psub_, *arrs):
                 out = prog.fn(dict(zip(prog.needs, arrs)), psub_)
@@ -964,10 +1008,12 @@ class Engine:
                 return tuple(out[k] for k in prog.outs)
             args = ins
             shift = 0
+        wrapped.__name__ = wrapped.__qualname__ = f"kitsune.{prog.name}"
         jit_kw = {}
         if donate:
             jit_kw["donate_argnums"] = tuple(p + shift for p in donate)
-        with warnings.catch_warnings(record=True) as caught:
+        with warnings.catch_warnings(record=True) as caught, \
+                span("compile_program", program=prog.name):
             # an unusable donation (XLA declined to alias, e.g. on CPU) is
             # only a missed reuse -- the dead buffer is freed either way.
             # RECORD instead of ignore: declined donations feed the telemetry
@@ -1041,14 +1087,13 @@ class Engine:
                 "plans": plans,
                 "bytes_saved": sum(p["bytes_saved"] for p in plans)}
 
-    # -- pre-plan reference loop (bench baseline + differential oracle) ----
+    # -- pre-plan reference loop (differential oracle) ---------------------
     def run_legacy(self, feeds: dict[str, jax.Array], params: dict,
                    measure: bool = True) -> ExecutionReport:
         """The historical dict-driven dispatch loop: per-program shape
         keying + cache lookups + dict feeds on EVERY call.  Numerically
-        identical to `run()`; kept so bench_dispatch can report the
-        before/after dispatch overhead and tests can differential-check the
-        plan runtime against it."""
+        identical to `run()`; kept so tests can differential-check the plan
+        runtime against it."""
         g = self.graph
         for n in g.topo():
             if n.kind in ("input", "const") and n.name not in feeds:

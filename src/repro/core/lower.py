@@ -60,6 +60,7 @@ from typing import Any, Callable
 import jax
 import numpy as np
 
+from ..spans import span
 from .costmodel import V5E, HwSpec, cost_kernel_site, cost_vertical
 from .graph import Graph, Node
 
@@ -691,22 +692,23 @@ def _measure_site(g: Graph, km: KernelMatch, cfg) -> tuple[float, float]:
 
     The candidates are timed INTERLEAVED (min of MEASURE_REPS alternating
     runs each): back-to-back blocks would let one host load spike decide
-    the verdict."""
+    the verdict.  The whole measurement is one `verdict_measure` span."""
     import time as _time
-    vals, params = _synth_site(g, km)
-    make_kernel_fn, closure_fn, args = _site_runner(g, km, vals, params)
-    fk = jax.jit(make_kernel_fn(km._call))
-    fc = jax.jit(closure_fn)
-    jax.block_until_ready(fk(*args))  # warmup: absorb compile
-    jax.block_until_ready(fc(*args))
-    t_kernel = t_closure = float("inf")
-    for _ in range(MEASURE_REPS):
-        t0 = _time.perf_counter()
-        jax.block_until_ready(fk(*args))
-        t_kernel = min(t_kernel, _time.perf_counter() - t0)
-        t0 = _time.perf_counter()
+    with span("verdict_measure", kernel=km.kernel):
+        vals, params = _synth_site(g, km)
+        make_kernel_fn, closure_fn, args = _site_runner(g, km, vals, params)
+        fk = jax.jit(make_kernel_fn(km._call))
+        fc = jax.jit(closure_fn)
+        jax.block_until_ready(fk(*args))  # warmup: absorb compile
         jax.block_until_ready(fc(*args))
-        t_closure = min(t_closure, _time.perf_counter() - t0)
+        t_kernel = t_closure = float("inf")
+        for _ in range(MEASURE_REPS):
+            t0 = _time.perf_counter()
+            jax.block_until_ready(fk(*args))
+            t_kernel = min(t_kernel, _time.perf_counter() - t0)
+            t0 = _time.perf_counter()
+            jax.block_until_ready(fc(*args))
+            t_closure = min(t_closure, _time.perf_counter() - t0)
     return t_kernel, t_closure
 
 
@@ -778,7 +780,7 @@ def _tune_match(g: Graph, km: KernelMatch, cfg):
     def build(cand):
         return make_kernel_fn(km._factory(replace(cfg, **cand)))
 
-    choice = autotune(key, cands, build, args)
+    choice = autotune(key, cands, build, args, kernel=km.kernel)
     if "refused" in choice:
         km.meta["refused"] = choice["refused"]
     blocks = {k: v for k, v in choice.items() if k not in ("us", "refused")}

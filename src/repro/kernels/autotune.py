@@ -22,6 +22,8 @@ from typing import Any, Callable, Iterable
 
 import jax
 
+from ..spans import span
+
 
 def time_fn(fn: Callable, args: tuple, iters: int = 2) -> float:
     """Best-of-`iters` wall-clock seconds of fn(*args); the untimed first
@@ -68,6 +70,11 @@ class TuneCache:
             return {"size": len(self._store), "hits": self.hits,
                     "misses": self.misses}
 
+    def items(self) -> list[tuple[Any, dict]]:
+        """(key, choice) of every cached site."""
+        with self._lock:
+            return list(self._store.items())
+
     def clear(self) -> None:
         with self._lock:
             self._store.clear()
@@ -82,7 +89,7 @@ def tune_cache() -> TuneCache:
 
 def autotune(key: tuple, candidates: Iterable[dict],
              build: Callable[[dict], Callable], args: tuple,
-             iters: int = 2) -> dict:
+             iters: int = 2, kernel: str = "") -> dict:
     """Pick the fastest candidate for one kernel site.
 
     `build(candidate)` returns the callable to time (it is jit-compiled
@@ -90,7 +97,8 @@ def autotune(key: tuple, candidates: Iterable[dict],
     candidate the compiler refuses (a tile the backend cannot lay out, more
     VMEM than it may use) is skipped and named in the winner's `refused`
     entry; if every candidate is refused, the last refusal is raised.  The
-    winner (augmented with its measured `us`) is cached under `key`."""
+    winner (augmented with its measured `us`) is cached under `key`.  The
+    search runs under an `autotune` span naming `kernel`."""
     cands = list(candidates)
     if not cands:
         return {}
@@ -103,20 +111,22 @@ def autotune(key: tuple, candidates: Iterable[dict],
         return choice
     best, best_t = None, float("inf")
     refused: list[str] = []
-    for cand in cands:
-        try:
-            compiled = jax.jit(build(cand)).lower(*args).compile()
-        except Exception as exc:  # noqa: BLE001 - any compiler refusal
-            if len(refused) == len(cands) - 1:
-                raise RuntimeError(f"autotune: the compiler refused all "
-                                   f"{len(cands)} candidates") from exc
-            why = (str(exc).strip().splitlines() or [type(exc).__name__])[0]
-            label = ",".join(f"{k}={v}" for k, v in sorted(cand.items()))
-            refused.append(f"{label}: {why[:80]}")
-            continue
-        t = time_fn(compiled, args, iters)
-        if t < best_t:
-            best, best_t = cand, t
+    with span("autotune", kernel=kernel, candidates=len(cands)):
+        for cand in cands:
+            try:
+                compiled = jax.jit(build(cand)).lower(*args).compile()
+            except Exception as exc:  # noqa: BLE001 - any compiler refusal
+                if len(refused) == len(cands) - 1:
+                    raise RuntimeError(f"autotune: the compiler refused all "
+                                       f"{len(cands)} candidates") from exc
+                why = (str(exc).strip().splitlines()
+                       or [type(exc).__name__])[0]
+                label = ",".join(f"{k}={v}" for k, v in sorted(cand.items()))
+                refused.append(f"{label}: {why[:80]}")
+                continue
+            t = time_fn(compiled, args, iters)
+            if t < best_t:
+                best, best_t = cand, t
     choice = dict(best)
     choice["us"] = best_t * 1e6
     if refused:

@@ -154,6 +154,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(b, hq, sq, d)
@@ -260,6 +261,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
         o, m, l = pl.pallas_call(
             kern, grid=(b * hkv, n_s), in_specs=in_specs,
             out_specs=out_specs, out_shape=out_shape,
+            name="flash_decode",
             interpret=interpret)(qr, kr, vr)
     else:
         vl = jnp.asarray(valid_len, jnp.int32)
@@ -271,6 +273,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
             out_specs=out_specs)
         o, m, l = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
+            name="flash_decode",
             interpret=interpret)(vl, qr, kr, vr)
     out = combine_partials(o, m, l)     # (b*hkv, group, d)
     return out.reshape(b, hq, 1, d).astype(q.dtype)

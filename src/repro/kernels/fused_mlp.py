@@ -155,6 +155,7 @@ def fused_mlp_fwd(x: jax.Array, w1: jax.Array, w2: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d_out), jnp.float32)],
         compiler_params=params,
+        name="fused_mlp_fwd",
         interpret=interpret,
     )(x, w1, w2)
 
@@ -191,6 +192,7 @@ def fused_mlp_swiglu_fwd(x: jax.Array, wg: jax.Array, wu: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d_out), jnp.float32)],
         compiler_params=params,
+        name="fused_mlp_swiglu_fwd",
         interpret=interpret,
     )(x, wg, wu, wd)
 
@@ -343,6 +345,7 @@ def fused_mlp_swiglu_bwd(x, wg, wu, wd, dy, *, act: str = "silu",
         out_shape=jax.ShapeDtypeStruct((m, d_in), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d_in), jnp.float32)],
         compiler_params=dx_params,
+        name="fused_mlp_swiglu_bwd_dx",
         interpret=interpret,
     )(x, wg, wu, wd, dy)
     dwg, dwu, dwd = pl.pallas_call(
@@ -369,6 +372,7 @@ def fused_mlp_swiglu_bwd(x, wg, wu, wd, dy, *, act: str = "silu",
                         pltpu.VMEM((d_in, block_h), jnp.float32),
                         pltpu.VMEM((block_h, d_out), jnp.float32)],
         compiler_params=dw_params,
+        name="fused_mlp_swiglu_bwd_dw",
         interpret=interpret,
     )(x, wg, wu, wd, dy)
     return (dx, dwg.astype(wg.dtype), dwu.astype(wu.dtype),
@@ -405,6 +409,7 @@ def fused_mlp_bwd(x, w1, w2, dy, *, act: str = "gelu", block_m: int = 128,
         out_shape=jax.ShapeDtypeStruct((m, d_in), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d_in), jnp.float32)],
         compiler_params=dx_params,
+        name="fused_mlp_bwd_dx",
         interpret=interpret,
     )(x, w1, w2, dy)
     dw1, dw2 = pl.pallas_call(
@@ -427,6 +432,7 @@ def fused_mlp_bwd(x, w1, w2, dy, *, act: str = "gelu", block_m: int = 128,
         scratch_shapes=[pltpu.VMEM((d_in, block_h), jnp.float32),
                         pltpu.VMEM((block_h, d_out), jnp.float32)],
         compiler_params=dw_params,
+        name="fused_mlp_bwd_dw",
         interpret=interpret,
     )(x, w1, w2, dy)
     return dx, dw1.astype(w1.dtype), dw2.astype(w2.dtype)

@@ -147,6 +147,7 @@ def paged_flash_decode(q: jax.Array, kp: jax.Array, vp: jax.Array,
             jax.ShapeDtypeStruct((b, n_s, hkv, group, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, n_s, hkv, group, 1), jnp.float32),
         ],
+        name="paged_flash_decode",
         interpret=interpret,
     )(tbl, vl, qr, k2, v2)
     out = combine_partials(o, m, l)     # (b, hkv, group, d)

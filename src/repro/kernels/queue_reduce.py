@@ -73,5 +73,6 @@ def queue_reduce(x: jax.Array, *, op: str = "sum", block_rows: int = 128,
         out_specs=pl.BlockSpec((block_rows, c), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_rows, c), jnp.float32)],
+        name="queue_reduce",
         interpret=interpret,
     )(x)
