@@ -20,11 +20,12 @@ member ops (post split-reduction, post epilogue-fusion) onto the kernels:
   * HINTED atomics in traced training graphs (core/trace.py `atomic_vjp`
     with `lower=` hints, installed by models/atoms.py during training
     capture) -> EXECUTABLE kernel calls: fused_mlp / fused_mlp_swiglu
-    forward and fused_mlp_bwd (two-matrix and gated) backward.  The atomic
-    registry pins those nodes' semantics, so opacity of the eval closure is
-    not a bar -- this is how the backward of a real `jax.grad` training
-    step runs the Fig 2(c) multicast kernels instead of replaying autodiff
-    closures.
+    forward and fused_mlp_bwd (two-matrix and gated) backward;
+    flash_attention forward and flash_attention_bwd (the dQ / dK-dV pair)
+    for the attention atoms.  The atomic registry pins those nodes'
+    semantics, so opacity of the eval closure is not a bar -- this is how
+    the backward of a real `jax.grad` training step runs the Fig 2(c)
+    multicast kernels instead of replaying autodiff closures.
 
 Every match is EXACT: a chain is only lowered when its intermediate values
 are single-consumer-internal and the member ops' semantics are fully known
@@ -283,8 +284,9 @@ def _attention_call(node: Node, decode: bool, cfg) -> Callable:
     return call
 
 
-def _atomic_mlp_fwd_call(inputs: list[str], act: str, cfg) -> Callable:
+def _atomic_mlp_fwd_call(inputs: list[str], meta: dict, cfg) -> Callable:
     x, w1, w2 = inputs
+    act = meta.get("act", "identity")
 
     def call(vals, params):
         from repro.kernels import mlp
@@ -292,8 +294,9 @@ def _atomic_mlp_fwd_call(inputs: list[str], act: str, cfg) -> Callable:
     return call
 
 
-def _atomic_swiglu_fwd_call(inputs: list[str], act: str, cfg) -> Callable:
+def _atomic_swiglu_fwd_call(inputs: list[str], meta: dict, cfg) -> Callable:
     x, wg, wu, wd = inputs
+    act = meta.get("act", "identity")
 
     def call(vals, params):
         from repro.kernels import mlp_swiglu
@@ -302,8 +305,9 @@ def _atomic_swiglu_fwd_call(inputs: list[str], act: str, cfg) -> Callable:
     return call
 
 
-def _atomic_mlp_bwd_call(inputs: list[str], act: str, cfg) -> Callable:
+def _atomic_mlp_bwd_call(inputs: list[str], meta: dict, cfg) -> Callable:
     x, w1, w2, dy = inputs
+    act = meta.get("act", "identity")
 
     def call(vals, params):
         from repro.kernels import mlp_bwd
@@ -312,8 +316,9 @@ def _atomic_mlp_bwd_call(inputs: list[str], act: str, cfg) -> Callable:
     return call
 
 
-def _atomic_swiglu_bwd_call(inputs: list[str], act: str, cfg) -> Callable:
+def _atomic_swiglu_bwd_call(inputs: list[str], meta: dict, cfg) -> Callable:
     x, wg, wu, wd, dy = inputs
+    act = meta.get("act", "identity")
 
     def call(vals, params):
         from repro.kernels import mlp_swiglu_bwd
@@ -322,8 +327,36 @@ def _atomic_swiglu_bwd_call(inputs: list[str], act: str, cfg) -> Callable:
     return call
 
 
-def _paged_decode_call(inputs: list[str], block_size: int, cfg) -> Callable:
+def _atomic_attention_fwd_call(inputs: list[str], meta: dict,
+                               cfg) -> Callable:
+    q, k, v, *w = inputs
+    causal = bool(meta["causal"])
+    cfg = replace(cfg, block_q=meta["block"], block_k=meta["block"])
+
+    def call(vals, params):
+        from repro.kernels import attention
+        return attention(vals[q], vals[k], vals[v], causal=causal,
+                         window=vals[w[0]] if w else None, cfg=cfg)
+    return call
+
+
+def _atomic_attention_bwd_call(inputs: list[str], meta: dict,
+                               cfg) -> Callable:
+    q, k, v, *w, dy = inputs
+    causal = bool(meta["causal"])
+    cfg = replace(cfg, block_q=meta["block"], block_k=meta["block"])
+
+    def call(vals, params):
+        from repro.kernels import attention_bwd
+        return attention_bwd(vals[q], vals[k], vals[v], vals[dy],
+                             causal=causal,
+                             window=vals[w[0]] if w else None, cfg=cfg)
+    return call
+
+
+def _paged_decode_call(inputs: list[str], meta: dict, cfg) -> Callable:
     q, kp, vp, tbl, vl = inputs
+    block_size = int(meta["block_size"])
 
     def call(vals, params):
         from repro.kernels import paged_decode_attention
@@ -356,13 +389,72 @@ def _queue_reduce_call(partial: Node, cfg) -> Callable:
 # matchers
 # ---------------------------------------------------------------------------
 
-# lower_hint family -> (kernel label, #inputs, call factory, extra meta)
-_HINTED_KERNELS: dict[str, tuple] = {
-    "mlp_fwd": ("fused_mlp", 3, _atomic_mlp_fwd_call, {}),
-    "swiglu_fwd": ("fused_mlp_swiglu", 4, _atomic_swiglu_fwd_call, {}),
-    "mlp_bwd": ("fused_mlp_bwd", 4, _atomic_mlp_bwd_call, {}),
-    "swiglu_bwd": ("fused_mlp_bwd", 5, _atomic_swiglu_bwd_call,
-                   {"gated": True}),
+def _mlp_gate(g: Graph, n: Node, meta: dict) -> str | dict:
+    act = meta.get("act", "identity")
+    if act not in _LOWERABLE_ACTS:
+        return f"act {act!r} has no kernel implementation"
+    if len(g.nodes[n.inputs[0]].out.shape) < 2:
+        return "input rank < 2"
+    return {}
+
+
+def _attention_gate(g: Graph, n: Node, meta: dict) -> str | dict:
+    """What the training kernels need of the operands: rank-4 q/k/v with
+    whole GQA groups, self-attention (sq == skv, the causal diagonal at
+    the origin), and a sequence the fixed tile rule divides."""
+    from repro.kernels.flash_attention import train_block
+    shapes = [tuple(g.nodes[i].out.shape) for i in n.inputs[:3]]
+    if any(len(s) != 4 for s in shapes):
+        return "q/k/v must be rank-4"
+    if meta.get("windowed"):
+        w = g.nodes[n.inputs[3]].out
+        if w.shape != () or np.dtype(w.dtype) != np.int32:
+            return "window must be an int32 scalar"
+    (_, hq, sq, _), (_, hkv, skv, _) = shapes[0], shapes[1]
+    if hq % hkv:
+        return f"{hq} query heads do not group over {hkv} kv heads"
+    if sq != skv:
+        return f"needs sq == skv (got {sq} vs {skv})"
+    block = train_block(sq)
+    if block is None:
+        return f"sequence {sq} not tileable"
+    return {"block": block}
+
+
+@dataclass(frozen=True)
+class _Hinted:
+    """One lower_hint family: the kernel label, operand count, call factory
+    `(inputs, meta, cfg) -> call`, the operand gate `(g, node, meta) ->
+    extra meta | reason`, and whether the match offers its factory to the
+    tile search (False: the tiles come from a fixed rule).  An attention
+    atom without a window operand has one operand fewer (hint `windowed`)."""
+    kernel: str
+    n_in: int
+    factory: Callable
+    gate: Callable
+    extra: tuple = ()
+    tuned: bool = True
+
+    def operands(self, meta: dict) -> int:
+        return self.n_in - (meta.get("windowed") is False)
+
+
+_HINTED_KERNELS: dict[str, _Hinted] = {
+    "mlp_fwd": _Hinted("fused_mlp", 3, _atomic_mlp_fwd_call, _mlp_gate),
+    "swiglu_fwd": _Hinted("fused_mlp_swiglu", 4, _atomic_swiglu_fwd_call,
+                          _mlp_gate),
+    "mlp_bwd": _Hinted("fused_mlp_bwd", 4, _atomic_mlp_bwd_call, _mlp_gate),
+    "swiglu_bwd": _Hinted("fused_mlp_bwd", 5, _atomic_swiglu_bwd_call,
+                          _mlp_gate, extra=(("gated", True),)),
+    "attention_fwd": _Hinted("flash_attention", 4, _atomic_attention_fwd_call,
+                             _attention_gate, tuned=False),
+    "attention_bwd": _Hinted("flash_attention_bwd", 5,
+                             _atomic_attention_bwd_call, _attention_gate,
+                             tuned=False),
+    # block-table-native decode: operands are (q, kp, vp, tables, valid);
+    # the pools are flat row pools, not activations, so nothing to gate
+    "paged_decode": _Hinted("paged_decode", 5, _paged_decode_call,
+                            lambda g, n, meta: {}),
 }
 
 
@@ -371,56 +463,41 @@ def _try_hinted_atomic(g: Graph, n: Node, mset: set[str], taken: set[str],
     """Atomic nodes whose registry entry carries a kernel-lowering hint
     (core/trace.py `atomic(..., lower=...)` / `atomic_vjp`).  The hint pins
     the node's semantics, so opacity of the eval closure is NOT a bar: this
-    is how traced training graphs get EXECUTABLE fused_mlp_bwd matches
-    instead of the plan-only dX/dW analysis of synthesized backwards."""
+    is how traced training graphs get EXECUTABLE kernel matches in both
+    directions -- fused_mlp / fused_mlp_swiglu and fused_mlp_bwd for the
+    MLP atoms, instead of the plan-only dX/dW analysis of synthesized
+    backwards, and flash_attention (forward) / flash_attention_bwd (the
+    dQ / dK-dV pair) for the attention atoms, whose window is a runtime
+    operand the kernels scalar-prefetch.  Each family gates on what the
+    operands show; a site that fails its gate keeps its closure with the
+    reason recorded.  The attention tiles come from a fixed rule
+    (`flash_attention.train_block`), so those sites add no tile search."""
     hint = n.attrs.get("lower_hint")
     if not hint:
         return None
     family, *opts = hint
     meta = dict(tuple(kv) for kv in opts)
-    if family in ("attention_fwd", "attention_bwd"):
-        # the training atomics keep attention single-node; the backward runs
-        # the recompute closure (chunked online-softmax + vjp) and the
-        # forward's window arrives as a runtime operand -- both stay on the
-        # jnp path for now (ROADMAP: attention-backward kernel)
-        note(n.name, "atomic attention: recompute/jnp closure path "
-                     "(window is a runtime operand; no backward kernel yet)")
-        return None
-    if family == "paged_decode":
-        # block-table-native decode: operands are (q, kp, vp, tables, valid)
-        # and block_size is the hint's only static -- no act/rank gating,
-        # the pools are flat row pools, not activations
-        if len(n.inputs) != 5:
-            note(n.name, f"paged_decode: expected 5 operands, "
-                         f"got {len(n.inputs)}")
-            return None
-
-        def make_paged(c):
-            return _paged_decode_call(list(n.inputs),
-                                      int(meta["block_size"]), c)
-
-        return KernelMatch("paged_decode", (n.name,), n.name, dict(meta),
-                           _call=make_paged(cfg), _factory=make_paged)
     spec = _HINTED_KERNELS.get(family)
     if spec is None:
         note(n.name, f"unknown lower hint {family!r}")
         return None
-    kernel, n_in, factory, extra = spec
-    if len(n.inputs) != n_in:
-        note(n.name, f"{kernel}: expected {n_in} operands, "
+    n_in = spec.operands(meta)
+    if len(n.inputs) < n_in:
+        note(n.name, f"{spec.kernel}: expected {n_in} operands, "
                      f"got {len(n.inputs)}")
         return None
-    act = meta.get("act", "identity")
-    if act not in _LOWERABLE_ACTS:
-        note(n.name, f"{kernel}: act {act!r} has no kernel implementation")
+    gated = spec.gate(g, n, meta)
+    if isinstance(gated, str):
+        note(n.name, f"{spec.kernel}: {gated}")
         return None
-    if len(g.nodes[n.inputs[0]].out.shape) < 2:
-        note(n.name, f"{kernel}: input rank < 2")
-        return None
+    meta = {**meta, **dict(spec.extra), **gated}
     tuple_valued = "n_outs" in n.attrs and family.endswith("_fwd")
 
     def make(c):
-        call = factory(list(n.inputs), act, c)
+        # the atom's own operands lead: partial evaluation (a layer scan's
+        # loop-invariant hoisting) appends the values it hoisted out of the
+        # impl after them, and the kernel does not read those
+        call = spec.factory(list(n.inputs[:n_in]), meta, c)
         if tuple_valued:
             # atomic jit nodes are tuple-valued (projections index them):
             # the kernel call must honor the same convention as the eval
@@ -428,8 +505,8 @@ def _try_hinted_atomic(g: Graph, n: Node, mset: set[str], taken: set[str],
             return lambda vals, params: (call(vals, params),)
         return call
 
-    return KernelMatch(kernel, (n.name,), n.name, {**meta, **extra},
-                       _call=make(cfg), _factory=make)
+    return KernelMatch(spec.kernel, (n.name,), n.name, meta, _call=make(cfg),
+                       _factory=make if spec.tuned else None)
 
 def _is_opaque(n: Node) -> bool:
     return "_eval" in n.attrs

@@ -6,8 +6,15 @@ QK^T producer stage and a PV consumer stage.  The (S, S) score matrix never
 exists in HBM (the BSP baseline writes it twice).
 
 Variants:
-  * flash_attention      -- prefill/training; causal and sliding-window masks,
-                            GQA (q-head groups share a kv head).
+  * flash_attention      -- prefill/training; causal and sliding-window masks
+                            (the window a runtime scalar), GQA (q-head groups
+                            share a kv head); tiles wholly outside the masks
+                            get no compute and no fetch.
+  * flash_attention_lse  -- the same, also returning the row log-sum-exp.
+  * flash_attention_bwd  -- the training backward: a dQ kernel and a dK/dV
+                            kernel, each recomputing its probability tile
+                            from q, k and the lse (the paper's Fig 2(c)
+                            multicast), dK/dV summed over the GQA group.
   * flash_decode         -- single-token decode with the KV sequence *split
                             over the grid* (the paper's Fig 2(b): reduction-dim
                             parallelism instead of batch parallelism), partial
@@ -73,91 +80,378 @@ def decode_tile_candidates(s_len: int,
     return cands
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                 scale: float, causal: bool, window: int | None,
-                 block_q: int, block_k: int, n_k: int):
-    kv = pl.program_id(2)
+def train_block(s: int) -> int | None:
+    """The fixed tile rule of the training kernels (`flash_attention_lse`
+    and the dQ / dK-dV pair): square blocks of 512 rows, else 256, else
+    128, else the whole sequence where it is at most 512 long; None where
+    none of these tiles `s` (the site then keeps its closure).  A rule, not
+    a search, so the training sites add no tile search to set-up.  On one
+    v5e at Phi-3-medium's attention (40 / 10 heads of 128, S 2048) blocks
+    of 512 ran the forward and the backward 1.6-1.8x faster than 256, and
+    1024 no faster."""
+    for blk in (512, 256, 128):
+        if s % blk == 0:
+            return blk
+    return s if s <= 512 else None
 
-    @pl.when(kv == 0)
+
+def _window_operand(window, s: int) -> jax.Array:
+    """The window as the kernels' scalar-prefetched int32 (1,) operand: a
+    static int, None (no window) or a traced scalar alike, clamped to `s`
+    (a window of `s` or more masks nothing), so one compiled kernel serves
+    windowed and global layers."""
+    w = jnp.asarray(s if window is None else window, jnp.int32)
+    return jnp.minimum(w, s).reshape(1)
+
+
+def _tile(i, j, w, *, causal, bq, bk):
+    """(visible, masked) of the (q block i, kv block j) tile: whether any
+    of its pairs is attended, and whether any of them is masked."""
+    q0, k0 = i * bq, j * bk
+    q1, k1 = q0 + bq - 1, k0 + bk - 1
+    visible = q0 - k1 < w
+    full = q1 - k0 < w
+    if causal:
+        visible = jnp.logical_and(visible, q1 >= k0)
+        full = jnp.logical_and(full, q0 >= k1)
+    return visible, jnp.logical_not(full)
+
+
+def _run_tile(i, j, w, body, **geom):
+    """Run `body(masked)` on a visible tile, with the mask only where the
+    tile straddles the diagonal or the window's edge; tiles above the
+    diagonal or behind the window get no compute."""
+    visible, masked = _tile(i, j, w, **geom)
+
+    @pl.when(jnp.logical_and(visible, jnp.logical_not(masked)))
+    def _full():
+        body(False)
+
+    @pl.when(jnp.logical_and(visible, masked))
+    def _edge():
+        body(True)
+
+
+def _mask(i, j, w, shape, *, causal, bq, bk):
+    qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    ki = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask = qi - ki < w
+    if causal:
+        mask = jnp.logical_and(mask, qi >= ki)
+    return mask
+
+
+def _kv_range(i, w, *, causal, bq, bk, n_k):
+    """First and last kv block that q block i attends to."""
+    q0 = i * bq
+    lo = jnp.maximum(q0 - w + 1, 0) // bk
+    hi = jnp.minimum((q0 + bq - 1) // bk, n_k - 1) if causal else n_k - 1
+    return lo, hi
+
+
+def _q_range(j, w, *, causal, bq, bk, n_q):
+    """First and last q block that attends to kv block j."""
+    k0 = j * bk
+    lo = k0 // bq if causal else 0
+    hi = jnp.minimum((k0 + bk + w - 2) // bq, n_q - 1)
+    return lo, hi
+
+
+def _clamp(x, lo, hi):
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+def _dot_nt(a, b):
+    """a @ b.T with f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a.T @ b with f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+# Lanes of the running softmax statistics: each row's max and sum are kept
+# replicated across one vreg's 128 lanes, so broadcasting them over a
+# (rows, block) tile copies whole vregs instead of broadcasting a column.
+STAT_LANES = 128
+
+
+def _bcast(stat, n: int):
+    """A lane-replicated (rows, STAT_LANES) statistic as (rows, n)."""
+    if n % stat.shape[1] == 0:
+        return jnp.tile(stat, (1, n // stat.shape[1]))
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], n))
+
+
+def _fwd_kernel(w_ref, q_ref, k_ref, v_ref, o_ref, *rest, scale, geom, n_k,
+                with_lse):
+    if with_lse:
+        lse_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        (m_ref, l_ref, acc_ref), lse_ref = rest, None
+    i, j = pl.program_id(1), pl.program_id(2)
+    w = w_ref[0]
+
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                       # (block_q, d)
-    k = k_ref[0]                       # (block_k, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    def update(masked):
+        s = _dot_nt(q_ref[0], k_ref[0]) * scale
+        if masked:
+            s = jnp.where(_mask(i, j, w, s.shape, **geom), s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _bcast(m_new, s.shape[1]))
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _bcast(alpha, acc_ref.shape[1]) + \
+            jnp.dot(p.astype(v_ref.dtype), v_ref[0],
+                    preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    q0 = pl.program_id(1) * block_q
-    k0 = kv * block_k
-    qi = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    ki = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = jnp.ones_like(s, dtype=jnp.bool_)
-    if causal:
-        mask &= qi >= ki
-    if window is not None:
-        mask &= qi - ki < window
-    s = jnp.where(mask, s, NEG_INF)
+    _run_tile(i, j, w, update, **geom)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(kv == n_k - 1)
+    @pl.when(j == n_k - 1)
     def _done():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / _bcast(l, acc_ref.shape[1])).astype(
+            o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, window: int | None = None,
-                    scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False) -> jax.Array:
-    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D); Hq % Hkv == 0."""
+def _fwd(q, k, v, window, *, causal, scale, block_q, block_k, interpret,
+         with_lse):
+    """The forward on flattened heads: o (B*Hq, Sq, D) and, `with_lse`, the
+    f32 row log-sum-exp (B*Hq, Sq, STAT_LANES), lane-replicated."""
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     assert hq % hkv == 0, (hq, hkv)
     group = hq // hkv
-    scale = scale if scale is not None else d ** -0.5
-    block_q = min(block_q, sq)
-    block_k = min(block_k, skv)
-    assert sq % block_q == 0 and skv % block_k == 0
-    n_q, n_k = sq // block_q, skv // block_k
+    bq, bk = min(block_q, sq), min(block_k, skv)
+    assert sq % bq == 0 and skv % bk == 0, (sq, skv, bq, bk)
+    n_q, n_k = sq // bq, skv // bk
+    geom = dict(causal=causal, bq=bq, bk=bk)
 
-    grid = (b * hq, n_q, n_k)
-    kern = functools.partial(
-        _attn_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, n_k=n_k)
+    def q_map(bh, i, j, w_ref):
+        return bh, i, 0
+
+    def kv_map(bh, i, j, w_ref):
+        # tiles outside the masks are clamped onto a neighbour the grid
+        # fetches anyway, so they cost no DMA
+        lo, hi = _kv_range(i, w_ref[0], n_k=n_k, **geom)
+        return bh // group, _clamp(j, lo, hi), 0
+
+    out_specs = [pl.BlockSpec((1, bq, d), q_map)]
+    out_shape = [jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((1, bq, STAT_LANES), q_map))
+        out_shape.append(jax.ShapeDtypeStruct((b * hq, sq, STAT_LANES),
+                                              jnp.float32))
+    kern = functools.partial(_fwd_kernel, scale=scale, geom=geom, n_k=n_k,
+                             with_lse=with_lse)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b * hq, n_q, n_k),
+        in_specs=[pl.BlockSpec((1, bq, d), q_map),
+                  pl.BlockSpec((1, bk, d), kv_map),
+                  pl.BlockSpec((1, bk, d), kv_map)],
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((bq, STAT_LANES), jnp.float32),
+                        pltpu.VMEM((bq, STAT_LANES), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)])
+    return pl.pallas_call(
+        kern, grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=_PARAMS, name="flash_attention",
+        interpret=interpret,
+    )(_window_operand(window, max(sq, skv)), q.reshape(b * hq, sq, d),
+      k.reshape(b * hkv, skv, d), v.reshape(b * hkv, skv, d))
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                    causal: bool = True, window=None,
+                    scale: float | None = None, block_q: int = 128,
+                    block_k: int = 128, interpret: bool = False) -> jax.Array:
+    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D); Hq % Hkv == 0.
+
+    `window` (keys with q_pos - k_pos >= window are masked) is None, a
+    static int or a traced int32 scalar: it is a runtime operand either
+    way.  Causal tiles above the diagonal are skipped and not fetched."""
+    b, hq, sq, d = q.shape
+    scale = scale if scale is not None else d ** -0.5
+    (o,) = _fwd(q, k, v, window, causal=causal, scale=scale,
+                block_q=block_q, block_k=block_k, interpret=interpret,
+                with_lse=False)
+    return o.reshape(b, hq, sq, d)
+
+
+def flash_attention_lse(q, k, v, *, causal: bool = True, window=None,
+                        scale: float | None = None, block_q: int = 128,
+                        block_k: int = 128, interpret: bool = False):
+    """`flash_attention` that also returns the f32 row log-sum-exp of the
+    scaled, masked scores, (B, Hq, Sq): what the backward kernels
+    recompute each probability tile from."""
+    b, hq, sq, d = q.shape
+    scale = scale if scale is not None else d ** -0.5
+    o, lse = _fwd(q, k, v, window, causal=causal, scale=scale,
+                  block_q=block_q, block_k=block_k, interpret=interpret,
+                  with_lse=True)
+    return o.reshape(b, hq, sq, d), lse[..., 0].reshape(b, hq, sq)
+
+
+# ---------------------------------------------------------------------------
+# backward: a dQ kernel and a dK/dV kernel (Fig 2c multicast)
+# ---------------------------------------------------------------------------
+
+def _probs(q, k, lse, scale, mask):
+    """The probability tile, recomputed from q, k and the row lse."""
+    s = _dot_nt(q, k) * scale
+    p = jnp.exp(s - _bcast(lse, s.shape[1]))
+    return p if mask is None else jnp.where(mask, p, 0.0)
+
+
+def _dq_kernel(w_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
+               acc_ref, *, scale, geom, n_k):
+    i, j = pl.program_id(1), pl.program_id(2)
+    w = w_ref[0]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def update(masked):
+        k = k_ref[0]
+        mask = (_mask(i, j, w, (q_ref.shape[1], k.shape[0]), **geom)
+                if masked else None)
+        p = _probs(q_ref[0], k, lse_ref[0], scale, mask)
+        ds = p * (_dot_nt(do_ref[0], v_ref[0]) -
+                  _bcast(di_ref[0], p.shape[1]))
+        acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
+                                preferred_element_type=jnp.float32)
+
+    _run_tile(i, j, w, update, **geom)
+
+    @pl.when(j == n_k - 1)
+    def _done():
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(w_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
+                dv_ref, dk_acc, dv_acc, *, scale, geom, n_q, n_t):
+    j, t = pl.program_id(1), pl.program_id(2)
+    i = t % n_q
+    w = w_ref[0]
+
+    @pl.when(t == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def update(masked):
+        q, do = q_ref[0], do_ref[0]
+        mask = (_mask(i, j, w, (q.shape[0], k_ref.shape[1]), **geom)
+                if masked else None)
+        p = _probs(q, k_ref[0], lse_ref[0], scale, mask)
+        dv_acc[...] += _dot_tn(p.astype(do.dtype), do)
+        ds = p * (_dot_nt(do, v_ref[0]) - _bcast(di_ref[0], p.shape[1]))
+        dk_acc[...] += _dot_tn(ds.astype(q.dtype), q)
+
+    _run_tile(i, j, w, update, **geom)
+
+    @pl.when(t == n_t - 1)
+    def _done():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def flash_attention_bwd(q, k, v, do, *, causal: bool = True, window=None,
+                        scale: float | None = None, block_q: int = 128,
+                        block_k: int = 128, interpret: bool = False):
+    """(dq, dk, dv) of `flash_attention(q, k, v)` against the cotangent
+    `do`, in the primals' dtypes.
+
+    Runs the forward with lse, then delta = rowsum(do * o) in f32, then two
+    kernels that each recompute their probability tile from q, k and the
+    lse -- the multicast of paper Fig 2(c): one over (q head, q block, kv
+    block) accumulating dQ, one over (kv head, kv block, group x q block)
+    accumulating dK and dV over the q heads that share the kv head (GQA),
+    so no per-q-head partials reach HBM."""
+    b, hq, sq, d = q.shape
+    _, hkv, skv, _ = k.shape
+    group = hq // hkv
+    scale = scale if scale is not None else d ** -0.5
+    bq, bk = min(block_q, sq), min(block_k, skv)
+    n_q, n_k = sq // bq, skv // bk
+    geom = dict(causal=causal, bq=bq, bk=bk)
+    o, lse = _fwd(q, k, v, window, causal=causal, scale=scale, block_q=bq,
+                  block_k=bk, interpret=interpret, with_lse=True)
+    dor = do.reshape(b * hq, sq, d)
+    di = jnp.sum(dor.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                 keepdims=True)
+    di = jnp.broadcast_to(di, lse.shape)           # lane-replicated, as lse
     qr = q.reshape(b * hq, sq, d)
     kr = k.reshape(b * hkv, skv, d)
     vr = v.reshape(b * hkv, skv, d)
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, i, j, g=group: (bh // g, j, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, i, j, g=group: (bh // g, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        name="flash_attention",
+    w = _window_operand(window, max(sq, skv))
+
+    def row_map(bh, i, j, w_ref):
+        return bh, i, 0
+
+    def kv_map(bh, i, j, w_ref):
+        lo, hi = _kv_range(i, w_ref[0], n_k=n_k, **geom)
+        return bh // group, _clamp(j, lo, hi), 0
+
+    rows = pl.BlockSpec((1, bq, d), row_map)
+    stat = pl.BlockSpec((1, bq, STAT_LANES), row_map)
+    kvs = pl.BlockSpec((1, bk, d), kv_map)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, geom=geom, n_k=n_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b * hq, n_q, n_k),
+            in_specs=[rows, kvs, kvs, rows, stat, stat], out_specs=rows,
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
+        compiler_params=_PARAMS, name="flash_attention_dq",
         interpret=interpret,
-    )(qr, kr, vr)
-    return out.reshape(b, hq, sq, d)
+    )(w, qr, kr, vr, dor, lse, di)
+
+    n_t = group * n_q
+
+    def q_map(bkv, j, t, w_ref):
+        lo, hi = _q_range(j, w_ref[0], n_q=n_q, **geom)
+        return bkv * group + t // n_q, _clamp(t % n_q, lo, hi), 0
+
+    def own_map(bkv, j, t, w_ref):
+        return bkv, j, 0
+
+    rows = pl.BlockSpec((1, bq, d), q_map)
+    stat = pl.BlockSpec((1, bq, STAT_LANES), q_map)
+    kvs = pl.BlockSpec((1, bk, d), own_map)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, geom=geom, n_q=n_q,
+                          n_t=n_t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b * hkv, n_k, n_t),
+            in_specs=[rows, kvs, kvs, rows, stat, stat],
+            out_specs=[kvs, kvs],
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(kr.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vr.shape, v.dtype)],
+        compiler_params=_PARAMS, name="flash_attention_dkv",
+        interpret=interpret,
+    )(w, qr, kr, vr, dor, lse, di)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
 # ---------------------------------------------------------------------------

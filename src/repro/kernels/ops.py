@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .flash_attention import combine_partials, flash_attention, flash_decode
+from .flash_attention import (combine_partials, flash_attention,
+                              flash_attention_bwd, flash_decode)
 from .paged_attention import paged_flash_decode
 from .fused_mlp import (block_h_for, fused_mlp_bwd, fused_mlp_fwd,
                         fused_mlp_swiglu_bwd, fused_mlp_swiglu_fwd)
@@ -180,11 +181,26 @@ def mlp_swiglu_bwd(x: jax.Array, wg, wu, wd, dy: jax.Array, *,
 
 def attention(q, k, v, *, causal=True, window=None,
               cfg: KernelConfig = KernelConfig()):
+    """`window` may be None, an int or a traced int32 scalar."""
     if cfg.use_pallas:
         return flash_attention(q, k, v, causal=causal, window=window,
                                block_q=cfg.block_q, block_k=cfg.block_k,
                                interpret=cfg.interpret)
     return ref.attention_ref(q, k, v, causal=causal, window=window)
+
+
+def attention_bwd(q, k, v, dy, *, causal=True, window=None,
+                  cfg: KernelConfig = KernelConfig()):
+    """(dq, dk, dv) of `attention(q, k, v)` against the cotangent `dy`:
+    with `use_pallas` the forward-with-lse and the dQ / dK-dV kernel pair,
+    otherwise the vjp of the oracle."""
+    if cfg.use_pallas:
+        return flash_attention_bwd(q, k, v, dy, causal=causal, window=window,
+                                   block_q=cfg.block_q, block_k=cfg.block_k,
+                                   interpret=cfg.interpret)
+    _, pull = jax.vjp(lambda q_, k_, v_: ref.attention_ref(
+        q_, k_, v_, causal=causal, window=window), q, k, v)
+    return pull(dy)
 
 
 def decode_attention(q, k, v, *, valid_len=None,

@@ -12,11 +12,14 @@ hints let the `lower_kernels` pass bind the nodes to the REAL kernels
 `fused_mlp_swiglu_bwd` backward); unlowered execution replays the oracles, so
 the two paths are numerically interchangeable.
 
-Attention stays a single node per direction too: the backward impl RECOMPUTES
-the forward (the chunked online-softmax) and pulls cotangents through
-`jax.vjp` inside one node -- the flash-style recompute path.  No attention
-backward kernel exists yet (ROADMAP), so lowering records a fallback reason
-and the recompute closure runs on the jnp path.
+Attention stays a single node per direction too.  Its impls are the jnp
+chunked online-softmax and, backward, a recompute of it with cotangents
+pulled through `jax.vjp` inside one node.  The hints bind the forward to
+`flash_attention` and the backward to `flash_attention_bwd` (forward with
+lse, then the dQ / dK-dV kernel pair, each recomputing its probability
+tile); the window rides as a runtime operand the kernels scalar-prefetch.
+Sites whose operands the kernels do not take (cross-attention with sq !=
+skv, an untileable sequence) keep the closure, with the reason recorded.
 
 `dataflow_training()` installs the atoms over `layers.mlp_block` and the
 `chunked_attention` entrypoints for the duration of a trace:
@@ -113,32 +116,39 @@ def paged_decode_atom(block_size: int):
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def attention_atom(causal: bool, chunk: int, orig=None):
-    """(q, k, v, window) -> chunked attention as a differentiable atomic.
+def attention_atom(causal: bool, chunk: int, windowed: bool = True,
+                   orig=None):
+    """(q, k, v[, window]) -> chunked attention as a differentiable atomic.
 
-    `window` is a runtime operand (per-layer scan xs), so it rides as an
-    array input past `n_diff` (zero cotangent).  The backward node
-    recomputes the forward and pulls (dq, dk, dv) via jax.vjp -- one
-    flash-recompute node."""
+    A `windowed` atom takes the window as a runtime operand (per-layer scan
+    xs), an array input past `n_diff` (zero cotangent); the other form has
+    no window.  The two are separate atoms because a constant window would
+    be folded into the atom by partial evaluation, and the kernel call
+    reads the atom's operands by position.  The backward node recomputes
+    the forward and pulls (dq, dk, dv) via jax.vjp -- one flash-recompute
+    node; lowered, it runs the flash-attention backward kernels instead."""
     attn = orig or lm.chunked_attention
 
-    def fwd(q, k, v, window):
-        return attn(q, k, v, causal=causal, window=window, chunk=chunk)
+    def fwd(q, k, v, *window):
+        return attn(q, k, v, causal=causal, window=window[0] if window
+                    else None, chunk=chunk)
 
-    def bwd(q, k, v, window, dy):
-        _, pull = jax.vjp(
-            lambda q_, k_, v_: attn(q_, k_, v_, causal=causal,
-                                    window=window, chunk=chunk), q, k, v)
+    def bwd(q, k, v, *rest):
+        *window, dy = rest
+        _, pull = jax.vjp(lambda q_, k_, v_: fwd(q_, k_, v_, *window),
+                          q, k, v)
         return pull(dy)
 
     def flops(in_avals, out_avals):
         return attention_flops(in_avals, out_avals)
 
-    return atomic_vjp(fwd, bwd, "attention", name=f"attn_c{int(causal)}",
+    hint = (("causal", causal), ("windowed", windowed))
+    return atomic_vjp(fwd, bwd, "attention",
+                      name=f"attn_c{int(causal)}{'w' if windowed else ''}",
                       n_diff=3,
                       flops=flops, bwd_flops=lambda i, o: 2 * flops(i, o),
-                      lower=("attention_fwd", ("causal", causal)),
-                      bwd_lower=("attention_bwd",))
+                      lower=("attention_fwd", *hint),
+                      bwd_lower=("attention_bwd", *hint))
 
 
 # ---------------------------------------------------------------------------
@@ -169,9 +179,10 @@ def dataflow_training():
         return constrain(y, "act_resid")
 
     def chunked_attention(q, k, v, *, causal=True, window=None, chunk=1024):
-        win = jnp.asarray(lm.HUGE_WINDOW if window is None else window,
-                          jnp.int32)
-        return attention_atom(causal, chunk, orig_attn_lm)(q, k, v, win)
+        if window is None:
+            return attention_atom(causal, chunk, False, orig_attn_lm)(q, k, v)
+        win = jnp.asarray(window, jnp.int32)
+        return attention_atom(causal, chunk, True, orig_attn_lm)(q, k, v, win)
 
     layers.mlp_block = mlp_block
     lm.chunked_attention = chunked_attention

@@ -166,8 +166,9 @@ def compile_train_step(cfg: ArchConfig, opt: Optimizer,
     survive capture as custom-vjp atomics in BOTH directions and the
     `lower_kernels` pass binds them to the real Pallas kernels
     (`fused_mlp_fwd` forward, `fused_mlp_bwd` backward -- the Fig 2(c)
-    multicast, executable, not plan-only).  Attention stays single-node with
-    a flash-style recompute backward on the jnp path.
+    multicast, executable, not plan-only).  Attention stays single-node in
+    both directions and lowers onto the flash-attention forward and its
+    dQ / dK-dV backward pair where the operands fit the kernels.
 
     Returns a TracedApp: `app(state, batch) -> (state, metrics)`, same
     contract as the raw step.  With `donate_state` (default) the state

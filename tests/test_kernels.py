@@ -8,10 +8,13 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import (KernelConfig, attention, decode_attention, mlp,
-                           mlp_swiglu, reduce)
+from repro.kernels import (KernelConfig, attention, attention_bwd,
+                           decode_attention, mlp, mlp_swiglu, reduce)
 from repro.kernels import ref
-from repro.kernels.flash_attention import combine_partials, flash_attention, flash_decode
+from repro.kernels.flash_attention import (combine_partials, flash_attention,
+                                           flash_attention_bwd,
+                                           flash_attention_lse, flash_decode,
+                                           train_block)
 from repro.kernels.fused_mlp import fused_mlp_bwd, fused_mlp_fwd, fused_mlp_swiglu_fwd
 from repro.kernels.queue_reduce import queue_reduce
 
@@ -174,6 +177,76 @@ class TestFlashAttention:
         np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-4)
 
 
+class TestFlashAttentionTraining:
+    """The training kernels -- forward with lse, and the dQ / dK-dV pair --
+    against `jax.vjp` of the jnp chunked attention the training atoms
+    replace, with the window as a runtime int32 scalar."""
+
+    @pytest.mark.parametrize("hq,hkv,causal,window", [
+        (4, 4, True, None),        # causal
+        (8, 2, True, None),        # GQA, group 4
+        (4, 1, True, 20),          # a window that restricts
+        (4, 4, True, 1000),        # a window wider than S
+        (2, 2, False, None),       # bidirectional (encoder self-attention)
+    ], ids=["causal", "gqa4", "window", "wide_window", "noncausal"])
+    def test_matches_chunked_vjp(self, hq, hkv, causal, window):
+        from repro.models.lm import HUGE_WINDOW, chunked_attention
+        b, s, d, blk = 1, 48, 16, 16
+        q = rand(20, (b, hq, s, d), jnp.float32)
+        k, v = (rand(21 + i, (b, hkv, s, d), jnp.float32) for i in range(2))
+        do = rand(23, (b, hq, s, d), jnp.float32)
+        w = jnp.asarray(HUGE_WINDOW if window is None else window, jnp.int32)
+        kw = dict(causal=causal, window=w, block_q=blk, block_k=blk,
+                  interpret=True)
+
+        def chunked(q_, k_, v_):
+            return chunked_attention(q_, k_, v_, causal=causal, window=w,
+                                     chunk=16)
+
+        want_o, pull = jax.vjp(chunked, q, k, v)
+        o, lse = flash_attention_lse(q, k, v, **kw)
+        np.testing.assert_allclose(o, want_o, rtol=2e-5, atol=2e-5)
+        # the row log-sum-exp of the scaled, masked scores
+        kk = jnp.repeat(k, hq // hkv, axis=1)
+        sc = jnp.einsum("bhqd,bhkd->bhqk", q, kk) * d ** -0.5
+        qi, ki = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+        mask = (qi - ki) < w
+        if causal:
+            mask &= qi >= ki
+        want_lse = jax.nn.logsumexp(jnp.where(mask, sc, -jnp.inf), axis=-1)
+        np.testing.assert_allclose(lse, want_lse, rtol=2e-5, atol=2e-5)
+        got = flash_attention_bwd(q, k, v, do, **kw)
+        for name, g, want in zip("qkv", got, pull(do)):
+            assert g.shape == want.shape and g.dtype == want.dtype
+            np.testing.assert_allclose(g, want, rtol=2e-5, atol=2e-5,
+                                       err_msg=f"d{name}")
+
+    def test_bf16_operands_keep_dtype(self):
+        """The cell's dtype: bf16 matmul operands, f32 statistics, outputs
+        in the primals' dtype."""
+        from repro.models.lm import chunked_attention
+        b, hq, hkv, s, d = 1, 4, 1, 32, 16
+        q = rand(30, (b, hq, s, d), jnp.bfloat16)
+        k, v = (rand(31 + i, (b, hkv, s, d), jnp.bfloat16) for i in range(2))
+        do = rand(33, (b, hq, s, d), jnp.bfloat16)
+        _, pull = jax.vjp(lambda *a: chunked_attention(*a, causal=True),
+                          q, k, v)
+        got = flash_attention_bwd(q, k, v, do, causal=True, block_q=16,
+                                  block_k=16, interpret=True)
+        for g, want in zip(got, pull(do)):
+            assert g.dtype == jnp.bfloat16
+            np.testing.assert_allclose(np.asarray(g, np.float32),
+                                       np.asarray(want, np.float32),
+                                       rtol=5e-2, atol=5e-2)
+
+    def test_train_block_rule(self):
+        assert train_block(2048) == 512
+        assert train_block(768) == 256
+        assert train_block(640) == 128
+        assert train_block(12) == 12
+        assert train_block(1000) is None
+
+
 class TestFlashDecode:
     @pytest.mark.parametrize("s,valid", [(512, 512), (512, 300), (1024, 17)])
     def test_split_k_decode(self, s, valid):
@@ -247,6 +320,22 @@ class TestOpsDispatch:
         a = mlp(x, w1, w2, cfg=KernelConfig(use_pallas=False))
         b = mlp(x, w1, w2, cfg=KC)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
+
+    def test_attention_bwd_dispatch(self):
+        """The backward's two paths -- the kernel pair and the vjp of the
+        oracle -- agree, with a runtime window."""
+        q = rand(0, (1, 4, 32, 16), jnp.float32)
+        k, v = rand(1, (1, 2, 32, 16), jnp.float32), rand(2, (1, 2, 32, 16), jnp.float32)
+        dy = rand(3, (1, 4, 32, 16), jnp.float32)
+        w = jnp.asarray(9, jnp.int32)
+        kc = KernelConfig(use_pallas=True, interpret=True, block_q=16,
+                          block_k=16)
+        a = attention_bwd(q, k, v, dy, window=w,
+                          cfg=KernelConfig(use_pallas=False))
+        b = attention_bwd(q, k, v, dy, window=w, cfg=kc)
+        for x, y in zip(a, b):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       rtol=2e-4, atol=2e-4)
 
     def test_decode_dispatch(self):
         q = rand(0, (1, 4, 1, 32), jnp.float32)

@@ -4,7 +4,8 @@ one chip of a described TPU v5e topology.  Nothing runs; the TPU compiler
 refuses here what it would refuse on the chip -- a tile that is not
 (8, 128)-aligned, more VMEM than a kernel may use -- which interpret mode
 never sees.  Widths are gemma3-1b's (d_model 1152, d_ff 6912, 4 query
-heads and 1 KV head of 256) plus an 8 x 128 KV-head layout.
+heads and 1 KV head of 256) plus an 8 x 128 KV-head layout, and the
+training attention kernels at Phi-3-medium's (40 / 10 heads of 128).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library."""
@@ -17,9 +18,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import (KernelConfig, attention, decode_attention, mlp,
-                           mlp_bwd, mlp_swiglu, mlp_swiglu_bwd,
-                           paged_decode_attention, reduce)
+from repro.kernels import (KernelConfig, attention, attention_bwd,
+                           decode_attention, mlp, mlp_bwd, mlp_swiglu,
+                           mlp_swiglu_bwd, paged_decode_attention, reduce)
+from repro.kernels.flash_attention import train_block
 
 D_MODEL, D_FF = 1152, 6912
 KC = KernelConfig(use_pallas=True, interpret=False)
@@ -89,6 +91,27 @@ def test_flash_attention(one_chip, window):
     qkv = ((1, 4, 2048, 256), BF)
     compile_on_chip(lambda q, k, v: attention(q, k, v, window=window, cfg=KC),
                     qkv, qkv, qkv, sharding=one_chip)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_training(one_chip, direction):
+    """The training kernels at Phi-3-medium's widths (40 query and 10 KV
+    heads of 128, S 2048), under the training sites' fixed tiles, with the
+    window a runtime scalar."""
+    blk = train_block(2048)
+    kc = KernelConfig(use_pallas=True, interpret=False, block_q=blk,
+                      block_k=blk)
+    q, kv = ((1, 40, 2048, 128), BF), ((1, 10, 2048, 128), BF)
+    w = ((), jnp.int32)
+    if direction == "fwd":
+        compile_on_chip(
+            lambda q, k, v, w: attention(q, k, v, window=w, cfg=kc),
+            q, kv, kv, w, sharding=one_chip)
+    else:
+        compile_on_chip(
+            lambda q, k, v, w, dy: attention_bwd(q, k, v, dy, window=w,
+                                                 cfg=kc),
+            q, kv, kv, w, q, sharding=one_chip)
 
 
 def test_flash_decode(one_chip):

@@ -149,6 +149,30 @@ class TestTrainDifferential:
         _assert_tree_close(rstate["params"], s["params"], f"{name} params")
         _assert_tree_close(rstate["opt"], s["opt"], f"{name} opt state")
 
+    def test_attention_lowers_both_directions_matches_raw(self):
+        """A dense step whose attention sites pass their (roofline)
+        verdicts: the forward and backward attention atoms run the
+        flash-attention kernels, and losses, parameters and optimizer state
+        still match raw jax.grad over several steps."""
+        cfg, opt, state, batch = _case("qwen1.5-32b", seed=9, seq=16)
+        app = compile_train_step(cfg, opt, _TC, state=_copy(state),
+                                 batch=batch, donate_state=False,
+                                 lowering_policy="cost")
+        kern = _kernels(app)
+        for label in ("flash_attention", "flash_attention_bwd"):
+            sites = kern.get(label, [])
+            assert len(sites) == cfg.n_layers, (label, len(sites))
+            assert all(m.executable and m.accepted for m in sites), label
+        raw = jax.jit(make_train_step(cfg, opt, _TC))
+        s, rstate = state, _copy(state)
+        for i in range(3):
+            s, m = app(s, batch)
+            rstate, rm = raw(rstate, batch)
+            np.testing.assert_allclose(float(m["loss"]), float(rm["loss"]),
+                                       rtol=1e-4, err_msg=f"step {i}")
+        _assert_tree_close(rstate["params"], s["params"], "params")
+        _assert_tree_close(rstate["opt"], s["opt"], "opt state")
+
     def test_bsp_mode_same_numerics(self):
         cfg, opt, state, batch = _case("gemma3-1b", seed=3)
         kit = compile_train_step(cfg, opt, _TC, state=_copy(state),
@@ -272,6 +296,32 @@ class TestTrainingAtoms:
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=2e-4, atol=2e-4)
 
+    def test_attention_atom_grad_lowers_both_directions(self):
+        """GQA attention atom under jax.grad: the forward lowers onto
+        flash_attention and the backward onto the flash_attention_bwd pair,
+        both executable, with the window a runtime operand."""
+        from repro.models.atoms import attention_atom
+        from repro.models.lm import chunked_attention
+        atom = attention_atom(True, 1024)
+        ks = jax.random.split(jax.random.PRNGKey(3), 3)
+        q = jax.random.normal(ks[0], (1, 8, 16, 8), jnp.float32)
+        k = jax.random.normal(ks[1], (1, 2, 16, 8), jnp.float32)
+        v = jax.random.normal(ks[2], (1, 2, 16, 8), jnp.float32)
+        win = jnp.asarray(6, jnp.int32)
+        loss = lambda q, k, v: jnp.sum(atom(q, k, v, win) ** 2)
+        app = repro.compile(jax.grad(loss, argnums=(0, 1, 2)), (q, k, v),
+                            mode="kitsune", lowering_policy="always")
+        kern = _kernels(app)
+        for label in ("flash_attention", "flash_attention_bwd"):
+            assert kern.get(label), f"no {label} match"
+            assert all(m.executable and m.accepted for m in kern[label])
+        want = jax.grad(lambda q, k, v: jnp.sum(chunked_attention(
+            q, k, v, causal=True, window=win) ** 2), argnums=(0, 1, 2))(
+                q, k, v)
+        for w, g in zip(want, app(q, k, v)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-4, atol=2e-4)
+
     def test_dataflow_training_restores_originals(self):
         from repro.models import atoms, layers, lm
         orig_mlp, orig_attn = layers.mlp_block, lm.chunked_attention
@@ -307,6 +357,9 @@ class TestTrainingAtoms:
 class TestDescribeTraining:
     def test_describe_shows_executable_backward(self):
         cfg, opt, state, batch = _case("whisper-small", seed=8)
+        # an encoder longer than the decoder: cross-attention has sq != skv
+        batch["frame_embeds"] = jnp.concatenate(
+            [batch["frame_embeds"]] * 2, axis=1)
         app = compile_train_step(cfg, opt, _TC, state=state, batch=batch,
                                  donate_state=False)
         text = app.describe()
@@ -315,5 +368,24 @@ class TestDescribeTraining:
         for line in text.splitlines():
             if "lowered fused_mlp_bwd" in line:
                 assert "(plan-only)" not in line
-        # attention backward records its recompute fallback reason
-        assert "atomic attention: recompute" in text
+        # self-attention sites match the flash-attention kernels both ways;
+        # only the cross-attention sites fall back, each with its reason
+        kern = _kernels(app)
+        assert kern.get("flash_attention") and kern.get("flash_attention_bwd")
+        g = app.graph
+        matched = {o for ms in kern.values() for m in ms for o in m.ops}
+        fallbacks = {op: why for p in app.lowering.pipelines.values()
+                     for op, why in p.fallbacks.items()}
+        n_cross = 0
+        for n in g.nodes.values():
+            if n.kind != "attention":
+                continue
+            sq, skv = (g.nodes[i].out.shape[2] for i in n.inputs[:2])
+            if sq == skv:
+                assert n.name in matched, n.name
+            else:
+                n_cross += 1
+                assert n.name not in matched
+                assert "needs sq == skv" in fallbacks[n.name]
+        assert n_cross == 2 * cfg.n_layers   # forward and backward
+        assert "needs sq == skv" in text
