@@ -3,6 +3,7 @@ compile counter, the profiler window and the device readings."""
 from __future__ import annotations
 
 import contextlib
+import importlib
 import json
 import sys
 import time
@@ -94,10 +95,23 @@ class Context:
         self.spans.append((name, t, time.perf_counter()))
 
     def ref_arch(self) -> dict:
-        """The sizes the reference reads: the run's `arch` plus the
-        published norm epsilon."""
+        """The sizes the reference reads: the run's `arch`, the published
+        norm epsilon, and the whole published config as `published` (layer
+        types, window, routing)."""
         return dict(self.config["arch"],
-                    rms_norm_eps=self.config["published"]["rms_norm_eps"])
+                    rms_norm_eps=self.config["published"]["rms_norm_eps"],
+                    published=self.config["published"])
+
+    def reference(self):
+        """The module `reference/<name>.py` that the configuration names;
+        exits non-zero with the name where there is none."""
+        name = self.config["reference"]
+        if not (name.isidentifier()
+                and (HERE / "reference" / f"{name}.py").is_file()):
+            sys.exit(f"run.py: configuration {self.config['name']!r} names "
+                     f"the reference {name!r}, and there is no "
+                     f"reference/{name}.py")
+        return importlib.import_module(f"reference.{name}")
 
     def arch(self):
         """The program's ArchConfig from the configuration file's `arch`."""

@@ -17,28 +17,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import flops
 import traffic
 import trace_reduce
 import weights
 from common import (CompileCounter, all_within, check, log,
                     memory_peak_bytes, profiled)
-from reference import dense_lm
 
 FIRST_STEPS = 3
 
 
 def _stacked_norms(tree) -> dict:
     """Per-leaf, per-layer float32 norms of a program-layout tree, named as
-    the reference names them (`<leaf>@<layer>` for layer leaves)."""
+    the reference names them (`<leaf>@<layer>` for layer leaves; slice g of
+    `blocks/sub<j>` is layer g * P + j, P the number of subs)."""
     out = {}
     for k, v in tree.items():
         if k == "blocks":
-            for name, x in _flat_blocks(v["sub0"]).items():
-                per = jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)),
-                                       axis=tuple(range(1, x.ndim))))
-                for i in range(x.shape[0]):
-                    out[f"{name}@{i}"] = per[i]
+            subs = len(v)
+            for j in range(subs):
+                for name, x in _flat_blocks(v[f"sub{j}"]).items():
+                    per = jnp.sqrt(jnp.sum(
+                        jnp.square(x.astype(jnp.float32)),
+                        axis=tuple(range(1, x.ndim))))
+                    for g in range(x.shape[0]):
+                        out[f"{name}@{g * subs + j}"] = per[g]
         else:
             out[k] = jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32))))
     return out
@@ -150,6 +152,7 @@ def run(ctx) -> dict:
     from repro.models import get_model
     from repro.train import TrainConfig, compile_train_step
 
+    ref = ctx.reference()
     a = ctx.ref_arch()
     hp = ctx.workload["optimizer"]
     mode = ctx.workload["options"]["step"]
@@ -162,16 +165,17 @@ def run(ctx) -> dict:
         return traffic.train_batches(ctx.seed, tr, a["vocab"])[:FIRST_STEPS]
 
     if ctx.control:
-        return control(ctx, a, hp, ref_batches, limits)
+        return control(ctx, ref, a, hp, ref_batches, limits)
 
     counter = CompileCounter()
     opt = make_optimizer(cfg, hp["total_steps"])
     tc = TrainConfig(remat=True, xent_chunk=hp["xent_chunk"])
     rec: dict = {"mode": mode, "tokens_per_step": tokens_per_step}
     with ctx.span("weights"):
-        params = weights.program_tree(ctx.seed, a)
-        weights.check_layout(params, jax.eval_shape(
-            get_model(cfg).init, jax.random.PRNGKey(0)))
+        shapes = jax.eval_shape(get_model(cfg).init, jax.random.PRNGKey(0))
+        subs = len(shapes["blocks"])
+        params = weights.program_tree(ctx.seed, a, ref, subs)
+        weights.check_layout(params, shapes)
         state = {"params": params, "opt": jax.jit(opt.init)(params)}
         del params
         batches = traffic.train_batches(ctx.seed, tr, a["vocab"])
@@ -207,7 +211,7 @@ def run(ctx) -> dict:
         # the initial weights made again as the program got them: a jit's
         # bf16 output.  Made inside the norms' jit, XLA on the TPU may keep
         # them in float32 (excess precision), off by their bf16 rounding.
-        p0 = weights.program_tree(ctx.seed, a)
+        p0 = weights.program_tree(ctx.seed, a, ref, subs)
         readings["delta_norms"] = {k: float(v) for k, v in
                                    change_norms(state["params"], p0).items()}
     del norms, change_norms, p0
@@ -233,7 +237,7 @@ def run(ctx) -> dict:
     log(f"[window] {n} steps in {window_s:.6f}s; compiles in window "
         f"{compiles}; last loss {last_loss}; peak_bytes_in_use {peak}")
     rec.update(window_s=window_s, steps=n, compiles=compiles,
-               flops_per_token=flops.train_flops_per_token(a, tr["seq"]),
+               flops_per_token=ref.train_flops_per_token(a, tr["seq"]),
                peaks=ctx.peaks, shapes={"m": tokens_per_step,
                                         "d": a["d_model"], "f": a["d_ff"]})
     if ctx.trace:
@@ -243,12 +247,12 @@ def run(ctx) -> dict:
     gc.collect()
 
     with ctx.span("reference"):
-        ref = dense_lm.train(ctx.seed, a, ref_batches(), hp, FIRST_STEPS)
-    checks, info = compare(readings, ref, limits)
+        want = ref.train(ctx.seed, a, ref_batches(), hp, FIRST_STEPS)
+    checks, info = compare(readings, want, limits)
     checks["compiles_in_window"] = check(compiles, 0)
     finite = bool(np.isfinite(last_loss))
     checks["window_loss_finite"] = check(0 if finite else 1, 0)
-    log_readings(readings, ref, info)
+    log_readings(readings, want, info)
     tokens = n * tokens_per_step
     return {"correct": all_within(checks), "attempted": n + FIRST_STEPS,
             "failed": 0 if finite else 1, "checks": checks,
@@ -271,15 +275,14 @@ def _lowering(app) -> dict:
     return sites
 
 
-def control(ctx, a, hp, ref_batches, limits) -> dict:
+def control(ctx, ref, a, hp, ref_batches, limits) -> dict:
     """The reference at float8 in the program's place, read against the
     float32 reference with the cell's limits."""
     batches = ref_batches()
-    low = dense_lm.train(ctx.seed, a, batches, hp, FIRST_STEPS,
-                         quant=dense_lm.fp8)
-    ref = dense_lm.train(ctx.seed, a, batches, hp, FIRST_STEPS)
-    checks, info = compare(low, ref, limits)
-    log_readings(low, ref, info)
+    low = ref.train(ctx.seed, a, batches, hp, FIRST_STEPS, quant=ref.fp8)
+    want = ref.train(ctx.seed, a, batches, hp, FIRST_STEPS)
+    checks, info = compare(low, want, limits)
+    log_readings(low, want, info)
     return {"correct": all_within(checks), "attempted": FIRST_STEPS,
             "failed": 0, "checks": checks,
             "memory_peak_bytes": memory_peak_bytes(), "e2e": {}, "rec": {}}

@@ -14,19 +14,63 @@ configuration files' `departures`:
 
 `quant` is applied to both operands of every matrix product; the control
 puts float8 there, the precision below the configuration's bfloat16.
+
+As every module under `reference/`, it also says what the model holds and
+what a trained token costs: `top_shapes`, `layer_shapes` (the leaves of one
+layer, as the program names them below `blocks/sub<j>/`) and
+`train_flops_per_token`, which `weights.py` and the driver read.
 """
 from __future__ import annotations
 
 import functools
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import flops
 import weights
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
+
+
+def top_shapes(a: dict) -> dict[str, tuple]:
+    shapes = {"embed": (a["vocab"], a["d_model"]),
+              "final_norm": (a["d_model"],)}
+    if not a.get("tie_embeddings", True):
+        shapes["unembed"] = (a["vocab"], a["d_model"])
+    return shapes
+
+
+def layer_shapes(a: dict, i: int) -> dict[str, tuple]:
+    """Leaves (name -> shape) of layer i; every layer is one dense GQA +
+    SwiGLU layer."""
+    d, q, kv, f = (a["d_model"], a["n_heads"] * a["head_dim"],
+                   a["n_kv_heads"] * a["head_dim"], a["d_ff"])
+    shapes = {"ln1": (d,), "attn/wq": (d, q), "attn/wk": (d, kv),
+              "attn/wv": (d, kv), "attn/wo": (q, d), "ln2": (d,),
+              "mlp/wg": (d, f), "mlp/wu": (d, f), "mlp/wd": (f, d)}
+    if a.get("qkv_bias"):
+        shapes.update({"attn/bq": (q,), "attn/bk": (kv,), "attn/bv": (kv,)})
+    return shapes
+
+
+def matmul_params(a: dict) -> int:
+    """Weights that multiply every token: the layers and the output head
+    (the embedding is a lookup)."""
+    d, q = a["d_model"], a["n_heads"] * a["head_dim"]
+    kv, f = a["n_kv_heads"] * a["head_dim"], a["d_ff"]
+    per_layer = d * q + 2 * d * kv + q * d + 3 * d * f
+    return a["n_layers"] * per_layer + a["vocab"] * d
+
+
+def train_flops_per_token(a: dict, seq: int) -> float:
+    """Forward and backward (3x the forward) per trained token, with causal
+    attention over (seq + 1) / 2 keys on average; recomputation is not
+    counted."""
+    return 3.0 * (2.0 * matmul_params(a) + flops.attn_flops(a, (seq + 1) / 2))
 
 
 def _scaled_cast(x, dtype):
@@ -219,8 +263,9 @@ def train(seed: int, a: dict, batches: list, hp: dict, steps: int,
     Returns the losses, each leaf's clipped gradient norm at step 1 and each
     leaf's change over the steps (leaf names `<leaf>@<layer>`)."""
     n = a["n_layers"]
-    params = (weights.top_params(seed, a, jnp.bfloat16),
-              [weights.layer_params(seed, a, i, jnp.bfloat16)
+    me = sys.modules[__name__]
+    params = (weights.top_params(seed, a, me, jnp.bfloat16),
+              [weights.layer_params(seed, a, i, me, jnp.bfloat16)
                for i in range(n)])
     p0 = {k: np.asarray(v.astype(F32)) for k, v in _flat(params).items()}
 

@@ -9,14 +9,22 @@ Everything is found by name under this directory:
     workloads/<cell>.json   driver, configuration, traffic, program options
                             and the limits that decide `correct`
     configs/<config>.json   the model at its published widths, the cut, and
-                            the plain reference it is checked against
+                            the name of the plain reference it is checked
+                            against
+    reference/<name>.py     that reference: `top_shapes(a)`,
+                            `layer_shapes(a, i)` (layer i's leaves, named as
+                            the program names them below `blocks/sub<j>/`),
+                            `train(seed, a, batches, hp, steps, quant=...)`,
+                            `fp8` (the control's precision) and
+                            `train_flops_per_token(a, seq)`
     traffic/<traffic>.json  parameters of the one traffic generator
     drivers/<driver>.py     `run(ctx) -> dict` (train)
     metrics/<metric>.py     `read(rec) -> float | None`, one per-layer metric
     peaks.json              peak FLOP/s and bytes/s by `device_kind`
 
-A cell, configuration, traffic mix or per-layer metric is added by adding
-its file and its `BENCHMARK.json` entry; nothing here names one.
+A cell, configuration (with its reference), traffic mix or per-layer metric
+is added by adding its files and its `BENCHMARK.json` entry; nothing here
+names one.
 
 The run needs a TPU: without one, or with fewer chips than the cell asks
 for, it exits non-zero and prints no result.  The last line of standard

@@ -1,12 +1,18 @@
-"""Seeded weights of a dense decoder, made by the benchmark.
+"""Seeded weights, made by the benchmark in the layout that the
+configuration's reference module declares.
 
 Every value is a function of (seed, leaf name, layer) alone, so the program
 gets the whole tree from one jitted call on the device, and the reference
 makes the same values again one layer at a time, from the seed, without
 taking anything that the program holds.
 
-The tree has the program's layout (stacked layers, `blocks/sub0/...`); the
-driver checks it against the program's own parameter shapes.
+The reference (`reference/<name>.py`) names the leaves: `top_shapes(a)`,
+and `layer_shapes(a, i)` for layer i, named as the program names them below
+`blocks/sub<j>/`.  The program stacks its layers in P kinds of sub-layer
+(`blocks/sub0` ... `blocks/sub<P-1>`; P is 1 for a dense stack, 2 for dense
+layers between expert layers), so layer i lies in `blocks/sub<i % P>` at
+index i // P.  The driver takes P from the program's own parameter shapes
+and checks the tree against them.
 """
 from __future__ import annotations
 
@@ -19,32 +25,10 @@ from common import seed_key
 
 NORM_SPREAD = 0.1      # norm scales are 1 + 0.1 N(0, 1), not all ones
 BIAS_SCALE = 0.02
-
-
-def layer_shapes(a: dict) -> dict[str, tuple]:
-    """Per-layer leaves (name -> shape) of one dense GQA + SwiGLU layer."""
-    d, q, kv, f = (a["d_model"], a["n_heads"] * a["head_dim"],
-                   a["n_kv_heads"] * a["head_dim"], a["d_ff"])
-    shapes = {"ln1": (d,), "attn/wq": (d, q), "attn/wk": (d, kv),
-              "attn/wv": (d, kv), "attn/wo": (q, d), "ln2": (d,),
-              "mlp/wg": (d, f), "mlp/wu": (d, f), "mlp/wd": (f, d)}
-    if a.get("qkv_bias"):
-        shapes.update({"attn/bq": (q,), "attn/bk": (kv,), "attn/bv": (kv,)})
-    return shapes
-
-
-def top_shapes(a: dict) -> dict[str, tuple]:
-    shapes = {"embed": (a["vocab"], a["d_model"]),
-              "final_norm": (a["d_model"],)}
-    if not a.get("tie_embeddings", True):
-        shapes["unembed"] = (a["vocab"], a["d_model"])
-    return shapes
-
-
-def _scale(name: str, shape: tuple) -> float:
-    if name in ("embed", "unembed"):
-        return 0.02
-    return shape[0] ** -0.5          # fan-in of a (in, out) matrix
+# (in, out) matrices, or stacks of them (experts), by leaf name; the scale
+# is their fan-in
+MATRICES = ("wq", "wk", "wv", "wo", "wg", "wu", "wd", "w1", "w2", "router")
+BIASES = ("bq", "bk", "bv")
 
 
 def base_key(seed: int):
@@ -60,12 +44,17 @@ def leaf(key, name: str, shape: tuple, layer: int = -1,
     key = jax.random.fold_in(key, layer + 1)
     z = jax.random.normal(key, shape, jnp.float32)
     base = name.rsplit("/", 1)[-1]
-    if base in ("ln1", "ln2", "final_norm"):
+    if base in ("embed", "unembed"):
+        v = 0.02 * z
+    elif base.startswith("ln") or base.endswith("norm"):
         v = 1.0 + NORM_SPREAD * z
-    elif base.startswith("b"):
+    elif base in BIASES:
         v = BIAS_SCALE * z
+    elif base in MATRICES:
+        v = shape[-2] ** -0.5 * z
     else:
-        v = _scale(base, shape) * z
+        raise ValueError(f"leaf {name!r}: weights.py has no init scale for "
+                         f"a leaf named {base!r}")
     return v.astype(dtype)
 
 
@@ -80,32 +69,41 @@ def _nest(flat: dict) -> dict:
     return out
 
 
-def program_tree_traced(key, a: dict) -> dict:
-    """The whole parameter tree in the program's layout (bf16), traceable
-    inside a jit."""
-    flat = {k: leaf(key, k, s) for k, s in top_shapes(a).items()}
-    for k, s in layer_shapes(a).items():
-        flat["blocks/sub0/" + k] = jnp.stack(
-            [leaf(key, k, s, layer=i) for i in range(a["n_layers"])])
+def program_tree_traced(key, a: dict, ref, subs: int) -> dict:
+    """The whole parameter tree in the program's layout (bf16), with its
+    layers stacked in `subs` kinds of sub-layer; traceable inside a jit."""
+    flat = {k: leaf(key, k, s) for k, s in ref.top_shapes(a).items()}
+    for j in range(subs):
+        layers = range(j, a["n_layers"], subs)
+        shapes = ref.layer_shapes(a, j)
+        for i in layers:
+            if ref.layer_shapes(a, i) != shapes:
+                raise ValueError(f"layers {j} and {i} of blocks/sub{j} "
+                                 f"declare different leaves")
+        for k, s in shapes.items():
+            flat[f"blocks/sub{j}/{k}"] = jnp.stack(
+                [leaf(key, k, s, layer=i) for i in layers])
     return _nest(flat)
 
 
-def program_tree(seed: int, a: dict) -> dict:
+def program_tree(seed: int, a: dict, ref, subs: int) -> dict:
     """The whole parameter tree, made on the device in one jitted call."""
-    return jax.jit(lambda k: program_tree_traced(k, a))(base_key(seed))
+    return jax.jit(lambda k: program_tree_traced(k, a, ref, subs))(
+        base_key(seed))
 
 
-def layer_params(seed: int, a: dict, layer: int, dtype=jnp.float32) -> dict:
+def layer_params(seed: int, a: dict, layer: int, ref,
+                 dtype=jnp.float32) -> dict:
     """One layer's leaves, as the program holds them (bf16), in `dtype`."""
-    fn = jax.jit(lambda k, i: {
-        name: leaf(k, name, s, layer=i).astype(dtype)
-        for name, s in layer_shapes(a).items()})
+    shapes = ref.layer_shapes(a, layer)
+    fn = jax.jit(lambda k, i: {name: leaf(k, name, s, layer=i).astype(dtype)
+                               for name, s in shapes.items()})
     return fn(base_key(seed), layer)
 
 
-def top_params(seed: int, a: dict, dtype=jnp.float32) -> dict:
+def top_params(seed: int, a: dict, ref, dtype=jnp.float32) -> dict:
     fn = jax.jit(lambda k: {name: leaf(k, name, s).astype(dtype)
-                            for name, s in top_shapes(a).items()})
+                            for name, s in ref.top_shapes(a).items()})
     return fn(base_key(seed))
 
 
